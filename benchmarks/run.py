@@ -72,7 +72,11 @@ def main() -> None:
     import importlib
     import json
 
+    from repro.compile_cache import enable_compile_cache
+
     from . import common
+
+    enable_compile_cache()
 
     print("name,seconds,derived", flush=True)
     failures = []

@@ -85,9 +85,6 @@ F32 = jnp.float32
 # split geometry); jax's jit cache then holds one executable per (lane
 # count, trace shape, lane sharding).
 _SWEEP_CACHE: Dict[Tuple, object] = {}
-# Fallback compile accounting for jax versions without the (private)
-# jit _cache_size API: one entry per distinct compiled signature.
-_SIGNATURES = set()
 
 
 def compile_count() -> int:
@@ -96,13 +93,9 @@ def compile_count() -> int:
     Counts entries in the underlying jit caches (one per distinct
     (machine, budget, engine, lane-count, trace-shape, sharding)
     combination) — tests assert a ≥4-policy sweep adds exactly one and
-    that a service-cache hit adds zero.  Falls back to the engine's own
-    signature accounting if the jit cache-size API is unavailable.
+    that a service-cache hit adds zero.
     """
-    sizes = [getattr(fn, "_cache_size", None) for fn in _SWEEP_CACHE.values()]
-    if all(s is not None for s in sizes):
-        return int(sum(s() for s in sizes))
-    return len(_SIGNATURES)
+    return int(sum(fn._cache_size() for fn in _SWEEP_CACHE.values()))
 
 
 def stack_policies(policies: Sequence[PolicyConfig]) -> PolicyConfig:
@@ -237,16 +230,19 @@ def sweep_lanes(mc: MachineConfig,
     run a reference path deliberately.
 
     ``telemetry`` (optional :class:`repro.obs.Telemetry`) records
-    host-side counters (lanes, fast vs event windows), a device-time
-    histogram and — when tracing — ``sweep.prepare`` / ``sweep.device``
-    spans plus one ``window.fast`` / ``window.event`` span per scan
-    window (window classification is host data; device time is
+    host-side counters (lanes, fast vs event windows), histograms of the
+    host preparation (schedule passes, window plan, input stacking), the
+    device run up to ``block_until_ready`` (compile included on a cold
+    call) and the readback, and — when tracing — ``sweep.prepare`` /
+    ``sweep.device`` spans plus one ``window.fast`` / ``window.event``
+    span per scan window (window classification is host data; device time is
     attributed uniformly across windows since the compiled scan is
     opaque).  Every hook is host-side Python: the compiled program and
     its outputs are bitwise-identical with telemetry on or off.
     """
     tel = or_null(telemetry)
     prep_t0 = tel.now()
+    prep_wall = time.perf_counter()
     if engine not in ("blocked", "per_step"):
         raise ValueError(f"unknown engine {engine!r}")
     if (engine != "blocked" or phase_b != "batched") and not debug:
@@ -373,9 +369,7 @@ def sweep_lanes(mc: MachineConfig,
                        init_state(mc))
 
     mesh = _resolve_lane_sharding(lane_sharding, L)
-    shard_key = None
     if mesh is not None:
-        shard_key = int(mesh.devices.size)
         lane_sh = NamedSharding(mesh, P("lanes"))
         rep_sh = NamedSharding(mesh, P())
         put = jax.device_put
@@ -390,14 +384,8 @@ def sweep_lanes(mc: MachineConfig,
         seg_of_leaf = put(seg_of_leaf, lane_sh)
 
     geom = plan.geom if plan is not None else None
-    sig_budget, sig_phase_b, sig_group = eff_budget, phase_b, eff_group
-    if engine == "blocked":
-        sig_budget, sig_phase_b, sig_group = _normalize_blocked(
-            eff_budget, phase_b, eff_group, geom)
     run_sweep = _sweep_runner(mc, eff_budget, phase_b, engine, eff_block,
                               eff_group, geom)
-    _SIGNATURES.add((mc, sig_budget, sig_phase_b, engine, eff_block,
-                     sig_group, geom, L, S, shard_key))
 
     if tel.enabled:
         tel.counter("sweep.calls", engine=engine).inc()
@@ -416,13 +404,16 @@ def sweep_lanes(mc: MachineConfig,
 
     dev_t0 = tel.now()
     wall_t0 = time.perf_counter()
-    final, outs = run_sweep(st0, lane_cc, lane_pc, xs, seg_of_map,
-                            seg_of_leaf)
+    final, outs = jax.block_until_ready(
+        run_sweep(st0, lane_cc, lane_pc, xs, seg_of_map, seg_of_leaf))
+    read_t0 = time.perf_counter()
     final = jax.device_get(final)
     outs = [np.asarray(o) for o in jax.device_get(outs)]
     if tel.enabled:
-        tel.histogram("sweep.device_seconds").observe(
-            time.perf_counter() - wall_t0)
+        tel.histogram("sweep.prepare_seconds").observe(wall_t0 - prep_wall)
+        tel.histogram("sweep.device_seconds").observe(read_t0 - wall_t0)
+        tel.histogram("sweep.readback_seconds").observe(
+            time.perf_counter() - read_t0)
     if dev_t0 is not None:
         dev_t1 = tel.now()
         tel.add_span("sweep.device", dev_t0, dev_t1, cat="engine",

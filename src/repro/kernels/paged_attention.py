@@ -32,10 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 NEG_INF = -1e30
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(tables, lengths,            # scalar-prefetch refs (SMEM)
             q_ref, k_ref, v_ref,        # VMEM blocks
@@ -118,7 +114,7 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, q, k_pool, v_pool)

@@ -52,33 +52,24 @@ def fingerprint() -> Dict[str, object]:
     Deliberately coarse: it must be stable across runs on one box (it
     keys baseline namespaces) yet distinguish a CPU runner from a
     GPU/TPU one.  jax import is lazy so schema validation and report
-    rendering never pay for device init.
+    rendering never pay for device init.  A device that fails to
+    initialise raises here: a record must name the device it ran on.
     """
     global _FINGERPRINT
     if _FINGERPRINT is None:
-        fp: Dict[str, object] = {
+        import jax
+        import numpy
+        devs = jax.devices()
+        _FINGERPRINT = {
             "platform": _platform.platform(),
             "machine": _platform.machine(),
             "python": _platform.python_version(),
+            "jax": jax.__version__,
+            "device_platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "numpy": numpy.__version__,
         }
-        try:
-            import jax
-            devs = jax.devices()
-            fp["jax"] = jax.__version__
-            fp["device_platform"] = devs[0].platform
-            fp["device_kind"] = devs[0].device_kind
-            fp["device_count"] = len(devs)
-        except Exception:  # noqa: BLE001 — fingerprint must never fail
-            fp["jax"] = "unavailable"
-            fp["device_platform"] = "unknown"
-            fp["device_kind"] = "unknown"
-            fp["device_count"] = 0
-        try:
-            import numpy
-            fp["numpy"] = numpy.__version__
-        except Exception:  # noqa: BLE001
-            fp["numpy"] = "unavailable"
-        _FINGERPRINT = fp
     return dict(_FINGERPRINT)
 
 
@@ -88,10 +79,11 @@ def namespace_of(fp: Dict[str, object]) -> str:
     All CPU backends share one namespace ("cpu" — CI runners and dev
     boxes gate against the same committed baselines); an accelerator
     gets its own (``gpu:nvidia-a100`` style), which the report treats as
-    un-baselined until seeded with ``--update-baselines``.
+    un-baselined until seeded with ``--update-baselines``.  A fingerprint
+    without a known platform never lands under ``cpu``.
     """
     plat = str(fp.get("device_platform", "unknown")).lower()
-    if plat in ("cpu", "unknown"):
+    if plat == "cpu":
         return "cpu"
     kind = str(fp.get("device_kind", "")).strip().lower()
     kind = "-".join(kind.split()) or "generic"

@@ -43,8 +43,8 @@ from .query import (SimFuture, SimQuery, lane_digest, query_cache_key,
                     spec_cache_key)
 from .resilience import (BrokerOverloadedError, BrokerTimeoutError,
                          CircuitBreaker, DeadlineExceededError,
-                         PoisonedQueryError, Quarantine, ResilienceConfig,
-                         ServiceError)
+                         DeviceProgramError, PoisonedQueryError, Quarantine,
+                         ResilienceConfig, ServiceError)
 from .search import grid_search, policy_grid, successive_halving
 
 __all__ = [
@@ -53,7 +53,7 @@ __all__ = [
     "grid_search", "policy_grid", "successive_halving",
     "Telemetry", "NullTelemetry",
     "ServiceError", "PoisonedQueryError", "DeadlineExceededError",
-    "BrokerOverloadedError", "BrokerTimeoutError",
+    "BrokerOverloadedError", "BrokerTimeoutError", "DeviceProgramError",
     "ResilienceConfig", "Quarantine", "CircuitBreaker",
     "FaultInjector", "FaultRule", "InjectedFault",
     "fail_once", "fail_n", "fail_lane", "fail_rate",

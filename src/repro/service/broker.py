@@ -42,8 +42,13 @@ Failure model (see :mod:`repro.service.resilience` for the taxonomy and
   retry       a failed batch execution is retried up to
               ``resilience.max_retries`` times with exponential backoff
               while the error looks transient (injected faults carry an
-              explicit flag; real device errors are treated as
-              retryable).
+              explicit flag; XLA errors are judged by status code,
+              ``resilience.is_transient``).
+  fail fast   a deterministic XLA failure (compile refusal,
+              ``RESOURCE_EXHAUSTED``, ``INVALID_ARGUMENT``, ...) belongs to
+              the program, not to a lane: every lane of the batch fails
+              with ``DeviceProgramError`` at once — no retry, no
+              bisection, no quarantine, no breaker trip.
   bisection   a persistent batch failure is isolated by bisection: each
               half re-runs as a normal ``sweep_lanes`` call (pow2 lane
               padding keeps compile-key quantization intact), recursing
@@ -85,8 +90,9 @@ from .cache import ResultCache
 from .query import (SimFuture, SimQuery, lane_digest, query_cache_key,
                     spec_cache_key)
 from .resilience import (BrokerOverloadedError, CircuitBreaker,
-                         DeadlineExceededError, PoisonedQueryError,
-                         Quarantine, ResilienceConfig)
+                         DeadlineExceededError, DeviceProgramError,
+                         PoisonedQueryError, Quarantine, ResilienceConfig,
+                         is_device_program_error, is_transient)
 
 
 @dataclasses.dataclass
@@ -571,6 +577,9 @@ class SimBroker:
         try:
             results = self._run_with_retries(bkey, live, blabel)
         except Exception as exc:  # noqa: BLE001 — typed handling below
+            if is_device_program_error(exc):
+                self._fail_program(live, exc)
+                return
             was_open = self.breaker.is_open(bkey)
             self.breaker.record_failure(bkey)
             self._update_degraded_gauge()
@@ -625,10 +634,7 @@ class SimBroker:
                                        degraded=degraded)
             except Exception as exc:  # noqa: BLE001 — classified below
                 tel.counter("broker.flush_failures").inc()
-                # injected faults carry an explicit transience flag; real
-                # device errors default to retryable
-                transient = getattr(exc, "transient", True)
-                if not transient or attempt >= rs.max_retries:
+                if not is_transient(exc) or attempt >= rs.max_retries:
                     raise
                 delay = rs.backoff(attempt)
                 tel.histogram("broker.backoff_seconds").observe(delay)
@@ -648,6 +654,9 @@ class SimBroker:
             results = self._run_lanes(bkey, pendings, blabel)
         except Exception as exc:  # noqa: BLE001
             self.telemetry.counter("broker.flush_failures").inc()
+            if is_device_program_error(exc):
+                self._fail_program(pendings, exc)
+                return
             if len(pendings) == 1:
                 self._poison(pendings[0], exc)
                 return
@@ -665,6 +674,16 @@ class SimBroker:
         err = PoisonedQueryError(digest, cause=cause)
         self._settle_lane(pend, error=err)
         self._flight_dump("broker.poison", err)
+
+    def _fail_program(self, pendings: Sequence[_Pending],
+                      cause: BaseException) -> None:
+        """A deterministic XLA failure: rerunning cannot succeed and no
+        lane is to blame, so the whole group fails at once."""
+        err = DeviceProgramError(cause)
+        self.telemetry.counter("broker.device_program_errors").inc()
+        for p in pendings:
+            self._settle_lane(p, error=err)
+        self._flight_dump("broker.device_program", err)
 
     def _bucket_tid(self, bkey: Tuple) -> int:
         tid = self._bucket_tids.get(bkey)

@@ -1,10 +1,11 @@
 """Failure vocabulary and resilience primitives for the service layer.
 
 The broker's failure model (see ``service.broker``): a microbatch flush
-can fail for three distinct reasons — a *poisoned lane* (one query
+can fail for four distinct reasons — a *poisoned lane* (one query
 deterministically kills the program it rides in), a *transient device
-error* (retry with backoff clears it), or *pressure* (deadlines already
-blown, admission queue over capacity).  Each gets a typed error so
+error* (retry with backoff clears it), a *device program error* (XLA
+refuses or cannot fit the program itself, whatever its lanes), or
+*pressure* (deadlines already blown, admission queue over capacity).  Each gets a typed error so
 clients and the search drivers can tell "your query is bad" from "the
 service is busy" from "you asked too late", and three small primitives
 implement the policy:
@@ -25,6 +26,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 
 class ServiceError(RuntimeError):
@@ -69,6 +72,42 @@ class BrokerOverloadedError(ServiceError):
         super().__init__(
             f"broker over admission cap ({pending}/{cap} pending lanes); "
             "lowest-priority work is rejected")
+
+
+class DeviceProgramError(ServiceError):
+    """The batch's device program failed deterministically: the compiler
+    refused it, it ran out of device memory, or XLA rejected an
+    argument.  Running it again cannot succeed and no single lane is to
+    blame, so every lane of the batch fails with it at once — no retry,
+    no bisection, no quarantine."""
+
+    def __init__(self, cause: BaseException):
+        super().__init__(f"device program failed: {cause}")
+        self.__cause__ = cause
+
+
+# XLA status codes after which re-running the same program may succeed.
+_TRANSIENT_XLA_CODES = ("UNAVAILABLE", "ABORTED", "DEADLINE_EXCEEDED",
+                        "CANCELLED")
+
+
+def is_transient(exc: BaseException) -> bool:
+    """Whether re-running a failed batch may succeed.
+
+    XLA runtime errors are judged by their status code: only
+    ``_TRANSIENT_XLA_CODES`` retry, while compile refusals,
+    ``RESOURCE_EXHAUSTED``, ``INVALID_ARGUMENT`` and the rest are
+    deterministic.  Injected faults carry an explicit ``transient`` flag;
+    any other error is treated as retryable."""
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        return str(exc).split(":", 1)[0].strip() in _TRANSIENT_XLA_CODES
+    return bool(getattr(exc, "transient", True))
+
+
+def is_device_program_error(exc: BaseException) -> bool:
+    """A deterministic XLA failure (see :class:`DeviceProgramError`)."""
+    return isinstance(exc, jax.errors.JaxRuntimeError) and \
+        not is_transient(exc)
 
 
 class BrokerTimeoutError(ServiceError):

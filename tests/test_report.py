@@ -28,6 +28,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from repro import compile_cache
+from repro.obs import bench as obsbench
 from repro.obs import (FlightRecorder, Histogram, Telemetry, make_record,
                        append_record, flatten_metrics, load_history, merge,
                        next_run_id, quantile_from_snapshot, validate_record,
@@ -64,6 +66,28 @@ def test_make_record_is_schema_valid():
     fp = rec["fingerprint"]
     assert fp["device_platform"] and fp["jax"] and fp["python"]
     assert rec["namespace"] == namespace_of(fp)
+
+
+def test_fingerprint_of_failed_device_is_not_filed_under_cpu(monkeypatch):
+    """A device that fails to initialise must not yield a record filed
+    under ``cpu``: the fingerprint raises, and a fingerprint without a
+    known platform gets a namespace of its own."""
+    import jax
+
+    def no_device():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(obsbench, "_FINGERPRINT", None)
+    monkeypatch.setattr(jax, "devices", no_device)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        obsbench.fingerprint()
+    assert obsbench._FINGERPRINT is None
+    assert namespace_of({"device_platform": "unknown",
+                         "device_kind": "unknown"}) != "cpu"
+    assert namespace_of({}) != "cpu"
+    assert namespace_of({"device_platform": "cpu"}) == "cpu"
+    assert namespace_of({"device_platform": "tpu",
+                         "device_kind": "TPU v5 lite"}) == "tpu:tpu-v5-lite"
 
 
 def test_validate_record_rejects():
@@ -378,6 +402,8 @@ def test_run_manifest_records_drivers_and_failures(tmp_path, monkeypatch):
     monkeypatch.setattr(runmod, "FIGURES", {
         "ok": ("fake_ok", "fake passing driver"),
         "bad": ("fake_bad", "fake failing driver")})
+    # keep the worker's jax config off the on-disk compile cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
     monkeypatch.setattr(common, "ART", tmp_path)
     monkeypatch.setattr(common, "HISTORY", tmp_path / "history.jsonl")
     monkeypatch.setitem(common._RUN_STATE, "run_id", None)

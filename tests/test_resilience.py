@@ -25,6 +25,7 @@ it via ``pytest -m chaos``.
 import dataclasses
 import random
 
+import jax
 import pytest
 
 try:
@@ -42,8 +43,10 @@ from repro.service.cache import DiskCacheTier, ResultCache
 from repro.service.resilience import (BrokerOverloadedError,
                                       BrokerTimeoutError, CircuitBreaker,
                                       DeadlineExceededError,
+                                      DeviceProgramError,
                                       PoisonedQueryError, Quarantine,
-                                      ResilienceConfig, ServiceError)
+                                      ResilienceConfig, ServiceError,
+                                      is_transient)
 
 from test_service import (FakeClock, MIXED_POLICIES, random_trace,
                           tiny_machine)
@@ -188,6 +191,48 @@ def test_transient_fault_retried_with_backoff(stub_exec):
     assert b.stats.retries == 2 and b.stats.quarantined == 0
     assert b._test_sleeps == [0.01, 0.02]
     assert not b.degraded_buckets()
+    assert b._fut_index == {}
+
+
+@pytest.mark.parametrize("message,transient", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm", False),
+    ("INVALID_ARGUMENT: Executable expected parameter 0 of size 64", False),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+    ("UNAVAILABLE: TPU chip is busy", True),
+])
+def test_xla_errors_classified_by_status_code(message, transient):
+    assert is_transient(jax.errors.JaxRuntimeError(message)) is transient
+
+
+def test_deterministic_device_error_fails_fast(monkeypatch):
+    """A deterministic XLA failure is the program's, not a lane's: the
+    batch runs once — no retry, no bisection, no quarantine, no breaker
+    trip — and every future holds a DeviceProgramError."""
+    boom = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+    calls = []
+
+    def exploding(mc, ccs, pcs, trs, **kw):
+        calls.append(len(pcs))
+        raise boom
+
+    monkeypatch.setattr(broker_mod, "sweep_lanes", exploding)
+    mc = tiny_machine()
+    tr = random_trace(mc, seed=21)
+    b = _broker(max_lanes=4,
+                resilience=ResilienceConfig(max_retries=3,
+                                            breaker_threshold=1))
+    futs = b.submit_many([SimQuery(trace=tr, policy=pc, machine=mc)
+                          for pc in MIXED_POLICIES[:3]])
+    b.drain()
+    assert calls == [4]                  # 3 lanes padded to 4, run once
+    for f in futs:
+        with pytest.raises(DeviceProgramError) as ei:
+            f.result()
+        assert ei.value.__cause__ is boom
+    assert b.stats.retries == 0 and b.stats.quarantined == 0
+    assert b._test_sleeps == []
+    assert len(b.quarantine) == 0 and not b.degraded_buckets()
     assert b._fut_index == {}
 
 
