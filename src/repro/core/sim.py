@@ -895,14 +895,17 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
              seg_of_map, seg_of_leaf):
         va_row, w_row, fid, llc_rate, sched_row, do_free, do_scan, \
             has_fault, valid = x
-        st = jax.lax.cond(do_free,
-                          lambda s: free_segment(s, fid, seg_of_map, seg_of_leaf),
-                          lambda s: s, st)
-        st = jax.lax.cond(do_scan,
-                          lambda s: scan_op(s, cc, pc, va_row, w_row),
-                          lambda s: s, st)
-
-        st, fault_mask = phase_a(st, cc, va_row, w_row, llc_rate)
+        with jax.named_scope("step.free"):
+            st = jax.lax.cond(
+                do_free,
+                lambda s: free_segment(s, fid, seg_of_map, seg_of_leaf),
+                lambda s: s, st)
+        with jax.named_scope("step.scan"):
+            st = jax.lax.cond(do_scan,
+                              lambda s: scan_op(s, cc, pc, va_row, w_row),
+                              lambda s: s, st)
+        with jax.named_scope("step.access"):
+            st, fault_mask = phase_a(st, cc, va_row, w_row, llc_rate)
 
         if phase_b == "batched":
             def run_phase_b(st):
@@ -916,7 +919,8 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
                 return st2
         # faults are bursty (populate) or rare (steady state): skip the
         # fault engine entirely on fault-free steps
-        st = jax.lax.cond(has_fault, run_phase_b, lambda s: s, st)
+        with jax.named_scope("step.fault"):
+            st = jax.lax.cond(has_fault, run_phase_b, lambda s: s, st)
         # idle pad rows of a time-blocked window carry valid=False and
         # must not advance the step clock (it stamps TLB LRU and bern)
         st = dataclasses.replace(
@@ -1178,6 +1182,12 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
       split   fast prefix -> per-step replay of the (narrow) event span
               -> fast suffix.
 
+    Each kind runs under ``jax.named_scope("window.<kind>")``, and the
+    per-step body's phases under ``step.free``, ``step.scan``,
+    ``step.access`` and ``step.fault`` (``_build_step``), so device ops
+    carry stable names in a profiler trace; scopes change op metadata
+    only.
+
     Segment capacities come from ``geom``; each segment's live length
     arrives as traced offsets (``a_idx``/``b_idx``) and is enforced
     in-body by masking ``valid`` (and ``va`` for the split span) — rows
@@ -1251,6 +1261,12 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
         return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
                             *chunks)
 
+    def scoped(name, branch):
+        def run(s):
+            with jax.named_scope(name):
+                return branch(s)
+        return run
+
     def window(carry, xw, cc, pc, seg_of_map, seg_of_leaf):
         (va_w, wr_w, fid_w, llc_w, sched_w, vl_w, df_w, ds_w, hf_w,
          kind, a_idx, b_idx) = xw
@@ -1260,7 +1276,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                             llc_w[:block], vl_w[:block])
             return s, pad_rows(o, block)
 
-        branches = [fast_whole]
+        branches = [scoped("window.fast", fast_whole)]
 
         if has_full:
             def full_replay(s):
@@ -1269,7 +1285,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                         ds_w[:block], hf_w[:block], vl_w[:block])
                 s, o = run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf)
                 return s, pad_rows(o, block)
-            branches.append(full_replay)
+            branches.append(scoped("window.full", full_replay))
 
         if hoist is not None:
             ph, qh = hoist
@@ -1290,7 +1306,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                                     dsl(vl_w, b_idx, qh))
                     chunks.append(o)
                 return s, pad_rows(cat_rows(chunks), ph + qh)
-            branches.append(hoist_window)
+            branches.append(scoped("window.hoist", hoist_window))
 
         if split is not None:
             ps, es, qs = split
@@ -1325,10 +1341,10 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                                     dsl(vl_w, b_idx, qs))
                     chunks.append(o)
                 return s, pad_rows(cat_rows(chunks), ps + es + qs)
-            branches.append(split_window)
+            branches.append(scoped("window.split", split_window))
 
         if len(branches) == 1:
-            return fast_whole(carry)
+            return branches[0](carry)
         return jax.lax.switch(kind, branches, carry)
 
     return window
@@ -1473,6 +1489,15 @@ class WindowPlan:
     @property
     def n_windows(self) -> int:
         return len(self.kind)
+
+    @property
+    def replay_rows(self) -> int:
+        """Rows the per-step body runs: ``block`` for each full window and
+        the split-span capacity ``Es`` for each split window (its masked
+        rows cost device time too)."""
+        _, n_full, _, n_split = self.counts
+        es = self.geom[2][1] if n_split else 0
+        return n_full * self.block + n_split * es
 
 
 def _q2(n: int) -> int:
@@ -1639,10 +1664,9 @@ class TieredMemSimulator:
     oracle suites still exercise them).
 
     ``telemetry`` (optional :class:`repro.obs.Telemetry`) records run
-    counters, the fast/event window classification and — when tracing —
-    a ``sim.run`` span plus per-window ``window.fast`` / ``window.event``
-    spans.  All hooks are host-side: the compiled program and its
-    outputs are bitwise-identical with telemetry on or off.
+    counters, the fast/event window classification and a ``sim.run``
+    span.  All hooks are host-side: the compiled program and its outputs
+    are bitwise-identical with telemetry on or off.
     """
 
     def __init__(self, mc: MachineConfig = MachineConfig(),
@@ -1667,7 +1691,15 @@ class TieredMemSimulator:
 
     def run(self, trace: Trace, state: Optional[SimState] = None) -> RunResult:
         tel = self.telemetry
-        run_t0 = tel.now()
+        with tel.span("sim.run", steps=trace.n_steps, engine=self.engine,
+                      trace=trace.name):
+            final, timeline = self._run(trace, state)
+        tel.counter("sim.runs", engine=self.engine).inc()
+        return RunResult(final_state=final, timeline=timeline,
+                         trace_name=trace.name, policy_label=self.pc.label())
+
+    def _run(self, trace: Trace, state: Optional[SimState]):
+        tel = self.telemetry
         mc = self.mc
         assert trace.va.shape[1] == mc.n_threads, \
             f"trace has {trace.va.shape[1]} threads, machine {mc.n_threads}"
@@ -1687,11 +1719,9 @@ class TieredMemSimulator:
             block = min(self.block, pow2ceil(trace.n_steps))
             xs, plan = blocked_xs(trace, mc, self.pc, start_step=start,
                                   block=block, sched=sched)
-            win_kind = None
             if tel.enabled:
                 # the host-side window classification is exactly the
                 # fast/full/hoist/split dispatch the blocked engine ran
-                win_kind = plan.kind        # branch 0 == fast path
                 n_fast, _, n_hoist, n_split = plan.counts
                 tel.counter("sim.windows_event").inc(
                     plan.n_windows - n_fast)
@@ -1700,37 +1730,16 @@ class TieredMemSimulator:
                 tel.counter("sim.windows_split").inc(n_split)
             run_all = _compiled_run(mc, budget, self.phase_b, "blocked",
                                     block, group, plan.geom)
-            dev_t0 = tel.now()
             final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,
                                   seg_of_leaf)
             timeline = {k: np.asarray(v)[plan.emit_valid]
                         for k, v in zip(TIMELINE_KEYS, outs)}
-            if dev_t0 is not None:
-                # the compiled scan is opaque: device time attributes
-                # uniformly across windows, the classification is exact
-                dev_t1 = tel.now()
-                w_dur = (dev_t1 - dev_t0) / max(len(win_kind), 1)
-                for i, k in enumerate(win_kind):
-                    tel.add_span(
-                        "window.event" if k else "window.fast",
-                        dev_t0 + i * w_dur, dev_t0 + (i + 1) * w_dur,
-                        cat="engine", tid=1, args={"window": i})
         else:
-            if tel.enabled:
-                tel.counter("sim.steps").inc(trace.n_steps)
+            tel.counter("sim.steps").inc(trace.n_steps)
             xs = trace_xs(trace, mc, self.pc, start_step=start, sched=sched)
             run_all = _compiled_run(mc, budget, self.phase_b, "per_step",
                                     0, group)
             final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,
                                   seg_of_leaf)
             timeline = {k: np.asarray(v) for k, v in zip(TIMELINE_KEYS, outs)}
-        final = jax.device_get(final)
-        if tel.enabled:
-            tel.counter("sim.runs", engine=self.engine).inc()
-            if run_t0 is not None:
-                tel.add_span("sim.run", run_t0, tel.now(), cat="engine",
-                             args={"steps": trace.n_steps,
-                                   "engine": self.engine,
-                                   "trace": trace.name})
-        return RunResult(final_state=final, timeline=timeline,
-                         trace_name=trace.name, policy_label=self.pc.label())
+        return jax.device_get(final), timeline

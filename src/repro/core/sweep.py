@@ -62,7 +62,6 @@ Constraints inherited from the step being compiled once for all lanes:
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -229,20 +228,22 @@ def sweep_lanes(mc: MachineConfig,
     callers get the blocked/batched fast path.  Pass ``debug=True`` to
     run a reference path deliberately.
 
-    ``telemetry`` (optional :class:`repro.obs.Telemetry`) records
-    host-side counters (lanes, fast vs event windows), histograms of the
-    host preparation (schedule passes, window plan, input stacking), the
-    device run up to ``block_until_ready`` (compile included on a cold
-    call) and the readback, and — when tracing — ``sweep.prepare`` /
-    ``sweep.device`` spans plus one ``window.fast`` / ``window.event``
-    span per scan window (window classification is host data; device time is
-    attributed uniformly across windows since the compiled scan is
-    opaque).  Every hook is host-side Python: the compiled program and
-    its outputs are bitwise-identical with telemetry on or off.
+    ``telemetry`` (optional :class:`repro.obs.Telemetry`) times the
+    call's layer boundaries as spans: ``sweep.prepare`` (entry to
+    dispatch) holding ``sweep.schedule`` (fault schedules, conflict-group
+    bound, event masks), ``sweep.plan`` (lane stacking, window plan and
+    tiles) and ``sweep.stage`` (host-to-device copies, initial state,
+    shardings, runner lookup); ``sweep.device`` (dispatch to
+    ``block_until_ready``, compile included on a cold call) and
+    ``sweep.readback``.  It counts lanes, windows by kind, the rows the
+    window scan covers (``sweep.rows``) and the rows the per-step body
+    replays (``sweep.replay_rows``).  On the device the window kinds and
+    step phases carry ``jax.named_scope`` names (``window.*``,
+    ``step.*``; see ``sim._build_blocked_body``).  Every hook is
+    host-side Python: the compiled program and its outputs are
+    bitwise-identical with telemetry on or off.
     """
     tel = or_null(telemetry)
-    prep_t0 = tel.now()
-    prep_wall = time.perf_counter()
     if engine not in ("blocked", "per_step"):
         raise ValueError(f"unknown engine {engine!r}")
     if (engine != "blocked" or phase_b != "batched") and not debug:
@@ -259,176 +260,157 @@ def sweep_lanes(mc: MachineConfig,
         raise ValueError(
             f"lane lists disagree: {len(ccs)} costs, {L} policies, "
             f"{len(tr_list)} traces")
-
     shape = tr_list[0].va.shape
-    for tr in tr_list:
-        if tr.va.shape != shape:
-            raise ValueError(
-                f"sweep traces must share one shape; got {tr.va.shape} vs "
-                f"{shape} — pad_trace() them first")
-    if shape[1] != mc.n_threads:
-        raise ValueError(f"traces have {shape[1]} threads, machine has "
-                         f"{mc.n_threads}")
-
-    periods = sorted({int(p.autonuma_period) for p in policies
-                      if bool(p.autonuma)})
-    if len(periods) > 1:
-        raise ValueError(
-            f"swept policies must share autonuma_period, got {periods}; the "
-            "scan schedule is lane-shared")
-    period = periods[0] if periods else int(policies[0].autonuma_period)
-    lane_budget = min(max(int(p.autonuma_budget) for p in policies),
-                      mc.n_map)
-    if budget is not None and budget < lane_budget:
-        raise ValueError(f"budget override {budget} below the lane maximum "
-                         f"{lane_budget}; a smaller top_k bound changes "
-                         "results")
-    eff_budget = min(budget if budget is not None else lane_budget, mc.n_map)
-
-    lane_pc = _stack_leaves(policies)
-    lane_cc = _stack_leaves(ccs)
-
-    # Host arrays are built per *unique trace object* and fanned out to
-    # lanes by index, so a bucket of queries sharing one trace pays one
-    # schedule pass and one stack.
-    uniq: Dict[int, int] = {}
-    uniq_traces: List[Trace] = []
-    lane_of = np.empty((L,), np.int64)
-    for i, tr in enumerate(tr_list):
-        j = uniq.setdefault(id(tr), len(uniq_traces))
-        if j == len(uniq_traces):
-            uniq_traces.append(tr)
-        lane_of[i] = j
-
     S = shape[0]
-    scheds = [fault_schedule(tr, mc) for tr in uniq_traces]
 
-    eff_group: Optional[int] = None
-    if phase_b == "batched":
-        lane_group = min(
-            pow2ceil(max(fault_group_bound(sc) for sc in scheds)),
-            mc.n_threads)
-        if group is not None and group < lane_group:
-            raise ValueError(f"group override {group} below the lane "
-                             f"maximum {lane_group}; a smaller conflict-"
-                             "group bound drops allocator requests")
-        eff_group = min(group if group is not None else lane_group,
-                        mc.n_threads)
+    with tel.span("sweep.prepare", lanes=L, steps=S, engine=engine):
+        for tr in tr_list:
+            if tr.va.shape != shape:
+                raise ValueError(
+                    f"sweep traces must share one shape; got {tr.va.shape} "
+                    f"vs {shape} — pad_trace() them first")
+        if shape[1] != mc.n_threads:
+            raise ValueError(f"traces have {shape[1]} threads, machine has "
+                             f"{mc.n_threads}")
 
-    def lanes(per_trace, dtype):
-        a = np.stack([np.asarray(x, dtype) for x in per_trace], axis=1)
-        return a[:, lane_of]
+        periods = sorted({int(p.autonuma_period) for p in policies
+                          if bool(p.autonuma)})
+        if len(periods) > 1:
+            raise ValueError(
+                f"swept policies must share autonuma_period, got {periods}; "
+                "the scan schedule is lane-shared")
+        period = periods[0] if periods else int(policies[0].autonuma_period)
+        lane_budget = min(max(int(p.autonuma_budget) for p in policies),
+                          mc.n_map)
+        if budget is not None and budget < lane_budget:
+            raise ValueError(f"budget override {budget} below the lane "
+                             f"maximum {lane_budget}; a smaller top_k bound "
+                             "changes results")
+        eff_budget = min(budget if budget is not None else lane_budget,
+                         mc.n_map)
 
-    va = lanes([tr.va for tr in uniq_traces], np.int32)          # [S, L, T]
-    wr = lanes([tr.is_write for tr in uniq_traces], bool)
-    fid = lanes([tr.free_seg for tr in uniq_traces], np.int32)   # [S, L]
-    llc = lanes([tr.llc for tr in uniq_traces], np.float32)
-    sched = lanes(scheds, np.uint8)                              # [S, L, T]
+        # Host arrays are built per *unique trace object* and fanned out to
+        # lanes by index, so a bucket of queries sharing one trace pays one
+        # schedule pass and one stack.
+        uniq: Dict[int, int] = {}
+        uniq_traces: List[Trace] = []
+        lane_of = np.empty((L,), np.int64)
+        for i, tr in enumerate(tr_list):
+            j = uniq.setdefault(id(tr), len(uniq_traces))
+            if j == len(uniq_traces):
+                uniq_traces.append(tr)
+            lane_of[i] = j
 
-    do_free = np.zeros((S,), bool)
-    has_fault = np.zeros((S,), bool)
-    for sc, tr in zip(scheds, uniq_traces):
-        do_free |= np.asarray(tr.free_seg) >= 0
-        has_fault |= (sc & SCHED_DO).any(axis=1)
-    do_scan = scan_step_mask(S, period,
-                             enabled=any(bool(p.autonuma) for p in policies))
+        with tel.span("sweep.schedule"):
+            scheds = [fault_schedule(tr, mc) for tr in uniq_traces]
+            eff_group: Optional[int] = None
+            if phase_b == "batched":
+                lane_group = min(
+                    pow2ceil(max(fault_group_bound(sc) for sc in scheds)),
+                    mc.n_threads)
+                if group is not None and group < lane_group:
+                    raise ValueError(
+                        f"group override {group} below the lane maximum "
+                        f"{lane_group}; a smaller conflict-group bound drops "
+                        "allocator requests")
+                eff_group = min(group if group is not None else lane_group,
+                                mc.n_threads)
+            do_free = np.zeros((S,), bool)
+            has_fault = np.zeros((S,), bool)
+            for sc, tr in zip(scheds, uniq_traces):
+                do_free |= np.asarray(tr.free_seg) >= 0
+                has_fault |= (sc & SCHED_DO).any(axis=1)
+            do_scan = scan_step_mask(
+                S, period, enabled=any(bool(p.autonuma) for p in policies))
 
-    eff_block = min(int(block), pow2ceil(S))
-    plan = None
-    if engine == "per_step":
-        xs = (jnp.asarray(va), jnp.asarray(wr), jnp.asarray(fid),
-              jnp.asarray(llc), jnp.asarray(sched), jnp.asarray(do_free),
-              jnp.asarray(do_scan), jnp.asarray(has_fault),
-              jnp.ones((S,), jnp.bool_))
-        lane_axis_of_x = (1, 1, 1, 1, 1, None, None, None, None)
-    else:
-        # window classification from the lane-union schedule; same
-        # 9-array order and pad fills as sim.blocked_xs
-        # (WINDOW_PAD_FILLS) — pad-row semantics must match the solo path
-        plan = plan_windows(do_free, do_scan, has_fault, S, eff_block)
-        va_w, wr_w, fid_w, llc_w, sched_w, vl_w, df_w, ds_w, hf_w = \
-            window_tiles(
-                (va, wr, fid, llc, sched, np.ones((S,), bool), do_free,
-                 do_scan, has_fault),
-                S, eff_block, rows_to=plan.rows_in)
-        xs = tuple(jnp.asarray(a) for a in
-                   (va_w, wr_w, fid_w, llc_w, sched_w, vl_w, df_w, ds_w,
-                    hf_w, plan.kind, plan.seg_a, plan.seg_b))
-        # windowed lane arrays carry the lane axis at position 2
-        lane_axis_of_x = (2, 2, 2, 2, 2, None, None, None, None, None,
-                          None, None)
+        def lanes(per_trace, dtype):
+            a = np.stack([np.asarray(x, dtype) for x in per_trace], axis=1)
+            return a[:, lane_of]
 
-    seg_maps = np.stack([np.asarray(tr.seg_of_map, np.int32)
-                         for tr in uniq_traces])
-    seg_of_map = jnp.asarray(seg_maps[lane_of])                  # [L, n_map]
-    seg_leafs = np.stack([np.asarray(seg_of_leaf_table(tr, mc))
-                          for tr in uniq_traces])
-    seg_of_leaf = jnp.asarray(seg_leafs[lane_of])                # [L, n_leaf]
+        with tel.span("sweep.plan"):
+            va = lanes([tr.va for tr in uniq_traces], np.int32)   # [S, L, T]
+            wr = lanes([tr.is_write for tr in uniq_traces], bool)
+            fid = lanes([tr.free_seg for tr in uniq_traces], np.int32)
+            llc = lanes([tr.llc for tr in uniq_traces], np.float32)
+            sched = lanes(scheds, np.uint8)                       # [S, L, T]
+            eff_block = min(int(block), pow2ceil(S))
+            plan = None
+            if engine == "per_step":
+                host_xs = (va, wr, fid, llc, sched, do_free, do_scan,
+                           has_fault, np.ones((S,), bool))
+                lane_axis_of_x = (1, 1, 1, 1, 1, None, None, None, None)
+            else:
+                # window classification from the lane-union schedule; same
+                # 9-array order and pad fills as sim.blocked_xs
+                # (WINDOW_PAD_FILLS) — pad-row semantics must match the
+                # solo path
+                plan = plan_windows(do_free, do_scan, has_fault, S,
+                                    eff_block)
+                host_xs = tuple(window_tiles(
+                    (va, wr, fid, llc, sched, np.ones((S,), bool), do_free,
+                     do_scan, has_fault),
+                    S, eff_block, rows_to=plan.rows_in)) + (
+                        plan.kind, plan.seg_a, plan.seg_b)
+                # windowed lane arrays carry the lane axis at position 2
+                lane_axis_of_x = (2, 2, 2, 2, 2, None, None, None, None,
+                                  None, None, None)
 
-    st0 = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (L,) + a.shape),
-                       init_state(mc))
+        with tel.span("sweep.stage"):
+            xs = tuple(jnp.asarray(a) for a in host_xs)
+            lane_pc = _stack_leaves(policies)
+            lane_cc = _stack_leaves(ccs)
+            seg_maps = np.stack([np.asarray(tr.seg_of_map, np.int32)
+                                 for tr in uniq_traces])
+            seg_of_map = jnp.asarray(seg_maps[lane_of])          # [L, n_map]
+            seg_leafs = np.stack([np.asarray(seg_of_leaf_table(tr, mc))
+                                  for tr in uniq_traces])
+            seg_of_leaf = jnp.asarray(seg_leafs[lane_of])        # [L, n_leaf]
+            st0 = jax.tree.map(
+                lambda a: jnp.broadcast_to(a[None], (L,) + a.shape),
+                init_state(mc))
 
-    mesh = _resolve_lane_sharding(lane_sharding, L)
-    if mesh is not None:
-        lane_sh = NamedSharding(mesh, P("lanes"))
-        rep_sh = NamedSharding(mesh, P())
-        put = jax.device_put
-        st0 = jax.tree.map(lambda a: put(a, lane_sh), st0)
-        lane_cc = jax.tree.map(lambda a: put(a, lane_sh), lane_cc)
-        lane_pc = jax.tree.map(lambda a: put(a, lane_sh), lane_pc)
-        xs = tuple(
-            put(x, rep_sh if ax is None else NamedSharding(
-                mesh, P(*([None] * ax + ["lanes"]))))
-            for x, ax in zip(xs, lane_axis_of_x))
-        seg_of_map = put(seg_of_map, lane_sh)
-        seg_of_leaf = put(seg_of_leaf, lane_sh)
+            mesh = _resolve_lane_sharding(lane_sharding, L)
+            if mesh is not None:
+                lane_sh = NamedSharding(mesh, P("lanes"))
+                rep_sh = NamedSharding(mesh, P())
+                put = jax.device_put
+                st0 = jax.tree.map(lambda a: put(a, lane_sh), st0)
+                lane_cc = jax.tree.map(lambda a: put(a, lane_sh), lane_cc)
+                lane_pc = jax.tree.map(lambda a: put(a, lane_sh), lane_pc)
+                xs = tuple(
+                    put(x, rep_sh if ax is None else NamedSharding(
+                        mesh, P(*([None] * ax + ["lanes"]))))
+                    for x, ax in zip(xs, lane_axis_of_x))
+                seg_of_map = put(seg_of_map, lane_sh)
+                seg_of_leaf = put(seg_of_leaf, lane_sh)
 
-    geom = plan.geom if plan is not None else None
-    run_sweep = _sweep_runner(mc, eff_budget, phase_b, engine, eff_block,
-                              eff_group, geom)
+            geom = plan.geom if plan is not None else None
+            run_sweep = _sweep_runner(mc, eff_budget, phase_b, engine,
+                                      eff_block, eff_group, geom)
 
-    if tel.enabled:
-        tel.counter("sweep.calls", engine=engine).inc()
-        tel.counter("sweep.lanes", engine=engine).inc(L)
-        if engine == "blocked":
-            n_fast, _, n_hoist, n_split = plan.counts
-            tel.counter("sweep.windows_event").inc(plan.n_windows - n_fast)
-            tel.counter("sweep.windows_fast").inc(n_fast)
-            tel.counter("sweep.windows_hoist").inc(n_hoist)
-            tel.counter("sweep.windows_split").inc(n_split)
-        else:
-            tel.counter("sweep.steps").inc(S)
-        if prep_t0 is not None:
-            tel.add_span("sweep.prepare", prep_t0, tel.now(), cat="engine",
-                         args={"lanes": L, "steps": S, "engine": engine})
+        if tel.enabled:
+            tel.counter("sweep.calls", engine=engine).inc()
+            tel.counter("sweep.lanes", engine=engine).inc(L)
+            if engine == "blocked":
+                n_fast, _, n_hoist, n_split = plan.counts
+                tel.counter("sweep.windows_event").inc(
+                    plan.n_windows - n_fast)
+                tel.counter("sweep.windows_fast").inc(n_fast)
+                tel.counter("sweep.windows_hoist").inc(n_hoist)
+                tel.counter("sweep.windows_split").inc(n_split)
+                tel.counter("sweep.rows").inc(plan.n_windows * eff_block)
+                tel.counter("sweep.replay_rows").inc(plan.replay_rows)
+            else:
+                tel.counter("sweep.steps").inc(S)
+                tel.counter("sweep.rows").inc(S)
+                tel.counter("sweep.replay_rows").inc(S)
 
-    dev_t0 = tel.now()
-    wall_t0 = time.perf_counter()
-    final, outs = jax.block_until_ready(
-        run_sweep(st0, lane_cc, lane_pc, xs, seg_of_map, seg_of_leaf))
-    read_t0 = time.perf_counter()
-    final = jax.device_get(final)
-    outs = [np.asarray(o) for o in jax.device_get(outs)]
-    if tel.enabled:
-        tel.histogram("sweep.prepare_seconds").observe(wall_t0 - prep_wall)
-        tel.histogram("sweep.device_seconds").observe(read_t0 - wall_t0)
-        tel.histogram("sweep.readback_seconds").observe(
-            time.perf_counter() - read_t0)
-    if dev_t0 is not None:
-        dev_t1 = tel.now()
-        tel.add_span("sweep.device", dev_t0, dev_t1, cat="engine",
-                     args={"lanes": L, "steps": S, "engine": engine})
-        if engine == "blocked":
-            # The compiled scan is opaque, so device wall time is
-            # attributed uniformly across windows; the window
-            # classification itself is exact (host-side schedule;
-            # branch 0 is the whole-window fast path).
-            n_w = plan.n_windows
-            w_dur = (dev_t1 - dev_t0) / max(n_w, 1)
-            for i, k in enumerate(plan.kind):
-                tel.add_span("window.event" if k else "window.fast",
-                             dev_t0 + i * w_dur, dev_t0 + (i + 1) * w_dur,
-                             cat="engine", tid=1, args={"window": i})
+    with tel.span("sweep.device", lanes=L, steps=S, engine=engine):
+        final, outs = jax.block_until_ready(
+            run_sweep(st0, lane_cc, lane_pc, xs, seg_of_map, seg_of_leaf))
+    with tel.span("sweep.readback"):
+        final = jax.device_get(final)
+        outs = [np.asarray(o) for o in jax.device_get(outs)]
     if engine == "blocked":
         # [n_windows, R_out, L] -> [steps, L]: pad and capacity-slack
         # rows dropped in step order via the plan's emission mask

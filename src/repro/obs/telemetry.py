@@ -7,6 +7,14 @@
 blessed replacement for ad-hoc ``BrokerStats.as_dict`` readouts in
 benchmark artifacts.
 
+``span(name, **args)`` is the one way a layer boundary is timed.  It
+observes the histogram ``<name>_seconds`` on ``time.perf_counter``,
+enters a ``jax.profiler.TraceAnnotation`` (so the span lands on the host
+plane of any profiler trace, on the device trace's clock, with ``args``
+as event stats) and, when tracing, records the Perfetto event on the same
+clock.  A span hands its args to the spans it holds, so the identifiers
+of a flush reach every span inside it.
+
 ``NULL`` is the near-zero-cost default: a :class:`NullTelemetry` whose
 ``counter/gauge/histogram`` return shared no-op twins and whose ``span``
 is a reusable no-op context manager.  Instrumented code holds exactly
@@ -14,7 +22,7 @@ one pattern::
 
     tel = telemetry if telemetry is not None else NULL
     tel.counter("broker.queries").inc()
-    with tel.span("bucket.sweep", args={...}):
+    with tel.span("sweep.device", lanes=8):
         ...
 
 so the off path costs one attribute load and one no-op call per site —
@@ -26,8 +34,11 @@ from __future__ import annotations
 
 import json
 import time
+from contextlib import contextmanager
 from pathlib import Path
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .tracing import SpanRecorder
@@ -36,12 +47,15 @@ from .tracing import SpanRecorder
 class Telemetry:
     """Live metrics registry + optional span recorder."""
 
-    def __init__(self, tracing: bool = False, clock=time.monotonic,
+    def __init__(self, tracing: bool = False, clock=time.perf_counter,
                  max_events: int = 200_000):
         self.metrics = MetricsRegistry()
+        self.clock = clock
         self.tracer: Optional[SpanRecorder] = (
             SpanRecorder(clock=clock, max_events=max_events)
             if tracing else None)
+        # args of the open spans, inherited by the spans they hold
+        self._held: Dict[str, object] = {}
 
     # -------------------------------------------------------- metrics --
     @property
@@ -62,11 +76,28 @@ class Telemetry:
         return self.metrics.histogram(name, **kw)
 
     # -------------------------------------------------------- tracing --
-    def span(self, name: str, cat: str = "service", tid: int = 0,
-             args: Optional[Dict] = None):
-        if self.tracer is None:
-            return _NULL_CTX
-        return self.tracer.span(name, cat=cat, tid=tid, args=args)
+    @contextmanager
+    def span(self, name: str, **args):
+        """Time the block as layer boundary ``name``: histogram
+        ``<name>_seconds``, a profiler ``TraceAnnotation`` carrying the
+        args (and those of every enclosing span) as stats, and the
+        Perfetto event when tracing."""
+        outer = self._held
+        args = {**outer, **args}
+        hist = self.metrics.histogram(f"{name}_seconds")
+        self._held = args
+        with TraceAnnotation(name, **args):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                t1 = self.clock()
+                self._held = outer
+                hist.observe(t1 - t0)
+                if self.tracer is not None:
+                    self.tracer.add_span(name, t0, t1,
+                                         cat=name.partition(".")[0],
+                                         args=args)
 
     def add_span(self, name: str, begin: float, end: float,
                  cat: str = "service", tid: int = 0,
@@ -81,8 +112,8 @@ class Telemetry:
             self.tracer.instant(name, cat=cat, tid=tid, args=args)
 
     def now(self) -> Optional[float]:
-        """Tracer-clock seconds for explicit add_span bounds (None when
-        tracing is off — pair with ``add_span``, which no-ops then)."""
+        """Clock seconds for the explicit bounds of ``add_span`` (None
+        when tracing is off — pair with ``add_span``, which no-ops then)."""
         return None if self.tracer is None else self.tracer.now()
 
     # -------------------------------------------------------- results --
@@ -173,8 +204,7 @@ class NullTelemetry(Telemetry):
     def histogram(self, name: str, **kw):
         return _NULL_METRIC
 
-    def span(self, name: str, cat: str = "service", tid: int = 0,
-             args: Optional[Dict] = None):
+    def span(self, name: str, **args):
         return _NULL_CTX
 
     def add_span(self, *a, **kw):

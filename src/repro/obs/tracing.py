@@ -5,7 +5,8 @@ Spans are recorded as *complete* events (``ph: "X"`` — one event
 carrying both timestamp and duration), which are balanced by
 construction and load directly in Perfetto / ``chrome://tracing``.
 Timestamps are microseconds relative to the recorder's construction, on
-the recorder's own monotonic clock — the broker's (possibly fake)
+the recorder's own clock (``time.perf_counter``, the clock
+``Telemetry.span`` times with) — the broker's (possibly fake)
 scheduling clock never leaks into exported traces, and a span emitted
 late with an earlier start (e.g. a queue-wait span recorded at flush
 time) still gets a non-negative timestamp.
@@ -30,7 +31,7 @@ _US = 1e6
 class SpanRecorder:
     """Append-only span/instant event log with trace_event export."""
 
-    def __init__(self, clock=time.monotonic, max_events: int = 200_000,
+    def __init__(self, clock=time.perf_counter, max_events: int = 200_000,
                  process_name: str = "repro-sim-service",
                  recent_events: int = 256):
         self.clock = clock

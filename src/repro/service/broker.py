@@ -175,14 +175,18 @@ class SimBroker:
                    caller owns their shape and bucket).
     cache / clock  injectable for sizing and for deterministic tests.
     telemetry      optional :class:`repro.obs.Telemetry`: per-query
-                   lifecycle spans (admit → queue → flush → sweep →
-                   resolve), queue-wait and flush-latency histograms,
-                   per-bucket compile counters, cache and per-policy-
-                   family migration counters.  Defaults to the no-op
-                   sink; every hook is host-side, so compiled programs
-                   and results are identical either way.  Note spans use
-                   the telemetry clock, while queue-wait *metrics* use
-                   the broker's injectable scheduling ``clock``.
+                   lifecycle spans (``query.admit`` → ``query.queue`` →
+                   ``broker.flush`` → ``sweep.*`` → ``broker.resolve``;
+                   the broker numbers its flushes, every span of a flush
+                   carries that number and the bucket label, and every
+                   ``query.*`` span its lane digest), queue-wait and
+                   flush-latency histograms, per-bucket compile
+                   counters, cache and per-policy-family migration
+                   counters.  Defaults to the no-op sink; every hook is
+                   host-side, so compiled programs and results are
+                   identical either way.  Note spans use the telemetry
+                   clock, while queue-wait *metrics* use the broker's
+                   injectable scheduling ``clock``.
     resilience     :class:`~repro.service.resilience.ResilienceConfig`
                    (retry/backoff, breaker, quarantine TTL, admission
                    cap, deadline grace).  Defaults are production-sane.
@@ -235,6 +239,8 @@ class SimBroker:
         # per-bucket tracks keep concurrent buckets' queue spans from
         # partially overlapping on one line)
         self._bucket_tids: Dict[Tuple, int] = {}
+        # flushes so far: the number every span of a flush carries
+        self._flush_no = 0
 
     # ------------------------------------------------------------------
     # admission
@@ -290,7 +296,8 @@ class SimBroker:
             fut._resolve(hit, from_cache=True)
             if admit_t0 is not None:
                 tel.add_span("query.admit", admit_t0, tel.now(),
-                             args={"cache_hit": True})
+                             args={"cache_hit": True,
+                                   "lane": lane_digest(key)})
             return fut
 
         digest = lane_digest(key)
@@ -322,7 +329,8 @@ class SimBroker:
         if admit_t0 is not None:
             tel.add_span("query.admit", admit_t0, tel.now(),
                          args={"cache_hit": False,
-                               "bucket": _bucket_label(bkey)})
+                               "bucket": _bucket_label(bkey),
+                               "lane": digest})
 
         if len(bucket) >= self.max_lanes:
             self._flush(bkey)
@@ -504,47 +512,45 @@ class SimBroker:
             return
         tel = self.telemetry
         blabel = _bucket_label(bkey) if tel.enabled else ""
-        flush_t0 = tel.now()
-        wall_t0 = time.perf_counter()
-        now = self.clock()
-        pendings = sorted(
-            bucket.values(),
-            key=lambda p: (-p.priority, p.deadline, p.enqueue_t))
-        batch = pendings[:self.max_lanes]
-        for p in batch:
-            del bucket[p.key]
-        if not bucket:
-            del self._buckets[bkey]
-        if tel.enabled:
-            qwait = tel.histogram("broker.queue_wait_seconds")
+        self._flush_no += 1
+        flush_no = self._flush_no
+        # every span inside (sweep.*, broker.resolve) inherits these ids
+        with tel.span("broker.flush", flush=flush_no, bucket=blabel):
+            now = self.clock()
+            pendings = sorted(
+                bucket.values(),
+                key=lambda p: (-p.priority, p.deadline, p.enqueue_t))
+            batch = pendings[:self.max_lanes]
             for p in batch:
-                # broker scheduling clock, matching max_wait semantics
-                qwait.observe(max(now - p.enqueue_t, 0.0))
-                if p.admit_t is not None and flush_t0 is not None:
-                    tel.add_span("query.queue", p.admit_t, flush_t0,
-                                 tid=self._bucket_tid(bkey),
-                                 args={"bucket": blabel,
-                                       "waiters": len(p.futures)})
+                del bucket[p.key]
+            if not bucket:
+                del self._buckets[bkey]
+            if tel.enabled:
+                qwait = tel.histogram("broker.queue_wait_seconds")
+                flush_t0 = tel.now()
+                for p in batch:
+                    # broker scheduling clock, matching max_wait semantics
+                    qwait.observe(max(now - p.enqueue_t, 0.0))
+                    if p.admit_t is not None:
+                        tel.add_span("query.queue", p.admit_t, flush_t0,
+                                     tid=self._bucket_tid(bkey),
+                                     args={"bucket": blabel,
+                                           "waiters": len(p.futures),
+                                           "lane": lane_digest(p.key),
+                                           "flush": flush_no})
 
-        live = self._shed_expired(batch, now)
-        if not live:
-            return                      # everything shed; nothing to run
-        self.stats.flushes += 1
-        if tel.enabled:
-            tel.counter("broker.flushes", bucket=blabel).inc()
+            live = self._shed_expired(batch, now)
+            if not live:
+                return                  # everything shed; nothing to run
+            self.stats.flushes += 1
+            if tel.enabled:
+                tel.counter("broker.flushes", bucket=blabel).inc()
 
-        if self.breaker.is_open(bkey):
-            self._flush_degraded(bkey, live, blabel)
-        else:
-            self._flush_batched(bkey, live, blabel)
-
-        if tel.enabled:
-            tel.histogram("broker.flush_seconds").observe(
-                time.perf_counter() - wall_t0)
+            if self.breaker.is_open(bkey):
+                self._flush_degraded(bkey, live, blabel)
+            else:
+                self._flush_batched(bkey, live, blabel)
             tel.gauge("broker.pending_lanes").set(self.pending_lanes())
-            if flush_t0 is not None:
-                tel.add_span("bucket.flush", flush_t0, tel.now(),
-                             args={"bucket": blabel, "lanes": len(live)})
 
     def _shed_expired(self, batch: Sequence[_Pending], now: float) \
             -> List[_Pending]:
@@ -593,7 +599,7 @@ class SimBroker:
                 self._bisect(bkey, live[mid:], blabel)
             return
         self.breaker.record_success(bkey)
-        self._resolve_batch(live, results, blabel)
+        self._resolve_batch(live, results)
 
     def _flush_degraded(self, bkey: Tuple, live: List[_Pending],
                         blabel: str) -> None:
@@ -611,7 +617,7 @@ class SimBroker:
                 clean = False
                 self._poison(p, exc)
                 continue
-            self._resolve_batch([p], [res], blabel)
+            self._resolve_batch([p], [res])
         if clean:
             self.breaker.record_success(bkey)
         else:
@@ -664,7 +670,7 @@ class SimBroker:
             self._bisect(bkey, pendings[:mid], blabel)
             self._bisect(bkey, pendings[mid:], blabel)
             return
-        self._resolve_batch(pendings, results, blabel)
+        self._resolve_batch(pendings, results)
 
     def _poison(self, pend: _Pending, cause: BaseException) -> None:
         digest = lane_digest(pend.key)
@@ -767,18 +773,14 @@ class SimBroker:
         return results[:len(pendings)]
 
     def _resolve_batch(self, pendings: Sequence[_Pending],
-                       results: Sequence[RunResult], blabel: str) -> None:
+                       results: Sequence[RunResult]) -> None:
         tel = self.telemetry
-        resolve_t0 = tel.now()
-        for p, res in zip(pendings, results):
-            self.cache.put(p.key, res)
-            self._settle_lane(p, result=res)
-        if tel.enabled:
-            self._record_summaries(pendings, results)
-            if resolve_t0 is not None:
-                tel.add_span("query.resolve", resolve_t0, tel.now(),
-                             args={"bucket": blabel,
-                                   "lanes": len(pendings)})
+        with tel.span("broker.resolve", lanes=len(pendings)):
+            for p, res in zip(pendings, results):
+                self.cache.put(p.key, res)
+                self._settle_lane(p, result=res)
+            if tel.enabled:
+                self._record_summaries(pendings, results)
 
     def _record_summaries(self, batch: Sequence[_Pending],
                           results: Sequence[RunResult]) -> None:

@@ -69,8 +69,8 @@ def grid_search(broker: SimBroker, mc: MachineConfig,
     tel = broker.telemetry
     queries = [SimQuery(trace=trace, policy=pc, cost=cc, machine=mc)
                for pc in policies]
-    with tel.span("search.grid", args={"candidates": len(queries),
-                                       "objective": objective}):
+    with tel.span("search.grid", candidates=len(queries),
+                  objective=objective):
         futs = broker.submit_many(queries)
         broker.drain()
     tel.counter("search.evaluations").inc(len(queries))
@@ -114,9 +114,8 @@ def successive_halving(broker: SimBroker, mc: MachineConfig,
     for r in range(rungs):
         rung_spec = dataclasses.replace(
             spec, run_steps=spec.run_steps * eta ** r)
-        with tel.span("search.rung",
-                      args={"rung": r, "run_steps": rung_spec.run_steps,
-                            "candidates": len(cands)}):
+        with tel.span("search.rung", rung=r, run_steps=rung_spec.run_steps,
+                      candidates=len(cands)):
             scored = grid_search(broker, mc, rung_spec, cands, cc=cc,
                                  objective=objective)
         if not scored:

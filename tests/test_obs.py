@@ -14,6 +14,10 @@ Three contracts:
    and lanes/pad-lanes figures are asserted exactly, plus a
    Perfetto-loadable trace carrying one span per query lifecycle stage
    (admit -> queue -> flush -> sweep -> resolve).
+4. **Device scopes and the profiler sink** — the compiled sweep names its
+   window kinds and step phases (``window.*``, ``step.*``), and a
+   ``jax.profiler`` trace holds each flush's span tree on its host plane,
+   every span carrying the flush number.
 """
 import json
 import math
@@ -195,7 +199,7 @@ def test_null_telemetry_is_inert_and_shared():
     NULL.gauge("g").set(3)
     NULL.histogram("h").observe(1.0)
     assert NULL.counter("x").snapshot() == 0
-    with NULL.span("s", args={"a": 1}):
+    with NULL.span("s", a=1):
         pass
     NULL.add_span("s", 0.0, 1.0)
     NULL.instant("i")
@@ -212,9 +216,13 @@ def test_telemetry_facade_tracing_toggle(tmp_path):
     off.counter("c").inc()
     assert off.now() is None
     off.add_span("never", 0.0, 1.0)        # no-op without a tracer
-    with off.span("also-never"):
+    with off.span("timed"):                # histogram only, no event
         pass
-    assert off.snapshot() == {"metrics": {"c": 1}}
+    snap = off.snapshot()
+    assert set(snap) == {"metrics"}
+    assert snap["metrics"]["c"] == 1
+    assert snap["metrics"]["timed_seconds"]["count"] == 1
+    assert set(snap["metrics"]) == {"c", "timed_seconds"}
     assert off.export_trace(tmp_path / "no.json") is False
 
     on = Telemetry(tracing=True, clock=TickClock())
@@ -277,11 +285,43 @@ def test_sweep_lanes_bitwise_identical_with_telemetry():
     n_windows = (m.value("sweep.windows_fast")
                  + m.value("sweep.windows_event"))
     assert n_windows == 1, "64-step trace, block=64 -> one window"
+    assert m.value("sweep.rows") == 64
+    # the populate faults and the free at step 30 span the window: the
+    # per-step body replays all of it
+    assert m.value("sweep.windows_event") == 1
+    assert m.value("sweep.windows_split") == 0
+    assert m.value("sweep.replay_rows") == 64
     names = tel.tracer.span_names()
-    assert names.count("sweep.prepare") == 1
-    assert names.count("sweep.device") == 1
-    assert sum(n.startswith("window.") for n in names) == n_windows
-    assert m.value("sweep.device_seconds")["count"] == 1
+    for name in ("sweep.prepare", "sweep.device", "sweep.readback")\
+            + PREPARE_PARTS:
+        assert names.count(name) == 1, name
+        assert m.value(f"{name}_seconds")["count"] == 1, name
+    assert not any(n.startswith("window.") for n in names)
+    assert_prepare_parts_nest(tel)
+
+
+PREPARE_PARTS = ("sweep.schedule", "sweep.plan", "sweep.stage")
+
+
+def assert_prepare_parts_nest(tel):
+    """Each ``sweep.prepare`` holds one schedule, plan and stage span, in
+    that order, whose seconds sum to no more than its own."""
+    ev = [e for e in tel.tracer.events if e["ph"] == "X"]
+    preps = [e for e in ev if e["name"] == "sweep.prepare"]
+    assert preps
+    for prep in preps:
+        lo, hi = prep["ts"], prep["ts"] + prep["dur"]
+        parts = [e for e in ev if e["name"] in PREPARE_PARTS
+                 and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
+        assert [e["name"] for e in parts] == list(PREPARE_PARTS)
+        assert all(e["args"] == prep["args"] for e in parts), \
+            "child spans inherit the prepare span's identifiers"
+    m = tel.metrics
+    parts_s = sum(m.value(f"{n}_seconds")["sum"] for n in PREPARE_PARTS)
+    assert parts_s <= m.value("sweep.prepare_seconds")["sum"]
+    for n in PREPARE_PARTS:
+        assert m.value(f"{n}_seconds")["count"] \
+            == m.value("sweep.prepare_seconds")["count"]
 
 
 def test_simulator_bitwise_identical_with_telemetry():
@@ -297,9 +337,8 @@ def test_simulator_bitwise_identical_with_telemetry():
     assert m.value("sim.runs", engine="blocked") == 1
     n_windows = m.value("sim.windows_fast") + m.value("sim.windows_event")
     assert n_windows == math.ceil(160 / 64)
-    names = tel.tracer.span_names()
-    assert names.count("sim.run") == 1
-    assert sum(n.startswith("window.") for n in names) == n_windows
+    assert tel.tracer.span_names() == ["sim.run"]
+    assert m.value("sim.run_seconds")["count"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -378,13 +417,18 @@ def test_64_query_burst_snapshot_and_trace(tmp_path):
     names = tel.tracer.span_names()
     assert names.count("query.admit") == 128
     assert names.count("query.queue") == 64
-    assert names.count("bucket.flush") == 1
+    assert names.count("broker.flush") == 1
     assert names.count("sweep.device") == 1
-    assert names.count("query.resolve") == 1
-    assert sum(n.startswith("window.") for n in names) >= 1
+    assert names.count("broker.resolve") == 1
+    assert not any(n.startswith("window.") for n in names)
+    assert_prepare_parts_nest(tel)
+    for n in PREPARE_PARTS + ("broker.resolve",):
+        assert m.value(f"{n}_seconds")["count"] == 1, n
     admits = [e for e in tel.tracer.events
               if e.get("name") == "query.admit" and e["ph"] == "X"]
     assert sum(e["args"]["cache_hit"] for e in admits) == 64
+    digests = {broker.query_digest(q) for q in queries}
+    assert {e["args"]["lane"] for e in admits} == digests
 
     # the exported trace is well-formed, balanced, Perfetto-loadable JSON
     path = tmp_path / "burst_trace.json"
@@ -411,3 +455,175 @@ def test_burst_pad_lanes_ratio_counted():
                               broker.canonical_trace(futs[0].query))
     assert tel.metrics.value("broker.pad_lanes",
                              bucket=_bucket_label(bkey)) == 1
+
+
+# ---------------------------------------------------------------------------
+# device scopes and the profiler sink
+# ---------------------------------------------------------------------------
+def scoped_trace(mc, mixed):
+    """64 steps for block 16 and scan period 16.  ``mixed``: window 0
+    holds a narrow populate span (split), windows 1 and 3 a lone scan tick
+    (hoist), window 2 a tick plus faults 13 rows apart (full); the other
+    rows re-access the populated pool.  Otherwise the threads stay idle
+    and, with AutoNUMA off, every window is fast."""
+    from test_blocked import make_trace
+    T = mc.n_threads
+    if not mixed:
+        return make_trace(mc, np.full((64, T), -1, np.int32))
+    rng = np.random.default_rng(7)
+    va = rng.integers(0, 4 * T, (64, T)).astype(np.int64)
+    va[:4] = np.arange(4 * T).reshape(4, T)
+    va[33, 0], va[45, 0] = 4 * T, 4 * T + 1
+    return make_trace(mc, (va << mc.map_shift).astype(np.int32))
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["all-fast", "mixed"])
+def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, mixed):
+    """The compiled lane sweep names each window kind its geometry
+    compiles (``window.*``) and, where it compiles the per-step body, each
+    step phase (``step.*``) in its ops' metadata."""
+    import importlib
+    import re
+    from test_blocked import tiny_machine as blocked_machine
+    sweep_mod = importlib.import_module("repro.core.sweep")
+
+    mc = blocked_machine()
+    pc = PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
+                      autonuma=mixed, autonuma_period=16, autonuma_budget=32)
+    texts = []
+    real = sweep_mod._sweep_runner
+
+    def spy(*a, **kw):
+        fn = real(*a, **kw)
+
+        def run(*args):
+            texts.append(fn.lower(*args).compile().as_text())
+            return fn(*args)
+        return run
+
+    monkeypatch.setattr(sweep_mod, "_sweep_runner", spy)
+    tel = Telemetry()
+    sweep_lanes(mc, [CostConfig()], [pc], [scoped_trace(mc, mixed)],
+                block=16, telemetry=tel)
+    m = tel.metrics
+    kinds = {"window.fast": m.value("sweep.windows_fast"),
+             "window.full": (m.value("sweep.windows_event")
+                             - m.value("sweep.windows_hoist")
+                             - m.value("sweep.windows_split")),
+             "window.hoist": m.value("sweep.windows_hoist"),
+             "window.split": m.value("sweep.windows_split")}
+    if mixed:
+        assert kinds == {"window.fast": 0, "window.full": 1,
+                         "window.hoist": 2, "window.split": 1}
+        assert m.value("sweep.replay_rows") == 16 + 4
+    else:
+        assert kinds == {"window.fast": 4, "window.full": 0,
+                         "window.hoist": 0, "window.split": 0}
+        assert m.value("sweep.replay_rows") == 0
+    assert m.value("sweep.rows") == 64
+    (text,) = texts
+    # under vmap a scope reads "vmap(step.access)"
+    scopes = set(re.findall(r"[/(]((?:window|step)\.[a-z]+)[/)]", text))
+    # fast is always compiled: it is branch 0 of every geometry
+    want = {"window.fast"} | {k for k, n in kinds.items() if n}
+    if kinds["window.full"] or kinds["window.split"]:
+        want |= {"step.free", "step.scan", "step.access", "step.fault"}
+    assert scopes == want
+
+
+def read_host_spans(trace_dir):
+    """(name, start_s, end_s, stats) of every ``broker.*`` and ``sweep.*``
+    host event in the one ``.xplane.pb`` under ``trace_dir``, read as
+    ``bench/devtrace.py`` reads the profiler's output."""
+    import warnings
+    from pathlib import Path
+    from jax.profiler import ProfileData
+    (path,) = sorted(Path(trace_dir).rglob("*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():
+        # the stats view's type warns that it names no module
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    out += [(ev.name, ev.start_ns * 1e-9,
+                             (ev.start_ns + ev.duration_ns) * 1e-9,
+                             dict(ev.stats)) for ev in line.events
+                            if ev.name.startswith(("broker.", "sweep."))]
+    return out
+
+
+def test_profiler_trace_holds_the_flush_span_tree(tmp_path):
+    """With metrics-only telemetry under ``jax.profiler``, each flush
+    lands on the host plane as ``broker.flush`` holding ``sweep.prepare``
+    (holding schedule, plan and stage), then ``sweep.device``,
+    ``sweep.readback`` and ``broker.resolve``, each carrying the flush
+    number and bucket label."""
+    import jax
+    mc = burst_machine()
+    pc = PolicyConfig(data_policy=FIRST_TOUCH, autonuma=False)
+    queries = [SimQuery(trace=random_trace(mc, steps=96, seed=500 + i),
+                        policy=pc, machine=mc) for i in range(4)]
+    broker = SimBroker(max_lanes=2, telemetry=Telemetry())
+    broker.run(queries[:2])                     # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        broker.run(queries[2:])
+    finally:
+        jax.profiler.stop_trace()
+    spans = read_host_spans(tmp_path)
+    flushes = [sp for sp in spans if sp[0] == "broker.flush"]
+    assert [sp[3]["flush"] for sp in flushes] == [2]
+    (_, lo, hi, ids) = flushes[0]
+    assert ids["bucket"] == _bucket_label(
+        broker._bucket_key(queries[2], queries[2].trace))
+
+    def inside(name, a, b):
+        found = [sp for sp in spans if sp[0] == name
+                 and a <= sp[1] and sp[2] <= b]
+        assert len(found) == 1, name
+        assert found[0][3]["flush"] == 2 and \
+            found[0][3]["bucket"] == ids["bucket"], name
+        return found[0]
+
+    prep = inside("sweep.prepare", lo, hi)
+    parts = [inside(n, prep[1], prep[2]) for n in PREPARE_PARTS]
+    assert [p[1] for p in parts] == sorted(p[1] for p in parts)
+    dev = inside("sweep.device", prep[2], hi)
+    back = inside("sweep.readback", dev[2], hi)
+    inside("broker.resolve", back[2], hi)
+
+
+def test_queue_span_names_the_flush_that_resolved_it():
+    """Each lane's ``query.queue`` carries the number of the flush whose
+    ``broker.resolve`` settled it, and its lane digest."""
+    from repro.service.query import lane_digest
+    mc = burst_machine()
+    pc = PolicyConfig(data_policy=FIRST_TOUCH, autonuma=False)
+    queries = [SimQuery(trace=random_trace(mc, steps=96, seed=600 + i),
+                        policy=pc, machine=mc) for i in range(6)]
+    tel = Telemetry(tracing=True)
+    broker = SimBroker(max_lanes=2, telemetry=tel)
+    settled = {}                                # lane digest -> clock
+    real_put = broker.cache.put
+
+    def put(key, res):
+        settled[lane_digest(key)] = tel.tracer._ts(tel.clock())
+        real_put(key, res)
+
+    broker.cache.put = put
+    broker.run(queries)
+
+    ev = [e for e in tel.tracer.events if e["ph"] == "X"]
+    resolves = [e for e in ev if e["name"] == "broker.resolve"]
+    assert sorted(e["args"]["flush"] for e in resolves) == [1, 2, 3]
+    queued = [e for e in ev if e["name"] == "query.queue"]
+    assert {e["args"]["lane"] for e in queued} \
+        == {broker.query_digest(q) for q in queries} == set(settled)
+    for e in queued:
+        t = settled[e["args"]["lane"]]
+        (res,) = [r for r in resolves
+                  if r["ts"] <= t <= r["ts"] + r["dur"]]
+        assert e["args"]["flush"] == res["args"]["flush"]
