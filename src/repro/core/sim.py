@@ -77,7 +77,9 @@ tick runs as fast-prefix -> hoisted scan op -> fast-suffix with *zero*
 per-step rows (so a ``period=512, block=64`` cadence no longer demotes
 one window in eight to per-step replay); a window with a narrow event
 span runs fast prefix/suffix around a per-step replay of just the span;
-only wide spans replay the whole window per-step.  Segment capacities
+only wide spans replay the whole window per-step — through a row body
+with no ``lax.cond`` at all where the window has no free, no scan tick
+and a fault on every row (a populate window).  Segment capacities
 are quantized to per-class pow2 maxima and folded into the compile key
 (``WindowPlan.geom``) with live lengths as traced data, so compiled
 programs keep quantizing across trace contents — the property the
@@ -891,19 +893,26 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
     # rides along as ordinary masked data.
     scan_op = _build_scan_op(mc, budget)
 
+    # ``lean=True`` is the row body of a window the host schedule shows
+    # has no free, no scan tick and a fault on every row (``plan_windows``,
+    # WIN_LEAN): it drops the free and scan conds (``cond(False, f, id)``
+    # is the identity) and runs phase B unconditionally (``cond(True, f,
+    # id)`` is ``f``), so it is bit-identical to the general row and only
+    # skips the carried-state copies in and out of each ``lax.cond``.
     def step(st: SimState, cc: CostConfig, pc: PolicyConfig, x,
-             seg_of_map, seg_of_leaf):
+             seg_of_map, seg_of_leaf, lean: bool = False):
         va_row, w_row, fid, llc_rate, sched_row, do_free, do_scan, \
             has_fault, valid = x
-        with jax.named_scope("step.free"):
-            st = jax.lax.cond(
-                do_free,
-                lambda s: free_segment(s, fid, seg_of_map, seg_of_leaf),
-                lambda s: s, st)
-        with jax.named_scope("step.scan"):
-            st = jax.lax.cond(do_scan,
-                              lambda s: scan_op(s, cc, pc, va_row, w_row),
-                              lambda s: s, st)
+        if not lean:
+            with jax.named_scope("step.free"):
+                st = jax.lax.cond(
+                    do_free,
+                    lambda s: free_segment(s, fid, seg_of_map, seg_of_leaf),
+                    lambda s: s, st)
+            with jax.named_scope("step.scan"):
+                st = jax.lax.cond(
+                    do_scan, lambda s: scan_op(s, cc, pc, va_row, w_row),
+                    lambda s: s, st)
         with jax.named_scope("step.access"):
             st, fault_mask = phase_a(st, cc, va_row, w_row, llc_rate)
 
@@ -920,7 +929,10 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
         # faults are bursty (populate) or rare (steady state): skip the
         # fault engine entirely on fault-free steps
         with jax.named_scope("step.fault"):
-            st = jax.lax.cond(has_fault, run_phase_b, lambda s: s, st)
+            if lean:
+                st = run_phase_b(st)
+            else:
+                st = jax.lax.cond(has_fault, run_phase_b, lambda s: s, st)
         # idle pad rows of a time-blocked window carry valid=False and
         # must not advance the step clock (it stamps TLB LRU and bern)
         st = dataclasses.replace(
@@ -1170,13 +1182,16 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
     :func:`plan_windows` — ``None`` (every window is fast: the compiled
     program contains no dispatch, no per-step body and no scan op at
     all) or ``(has_full, (Ph, Qh) | None, (Ps, Es, Qs) | None)``.  The
-    body dispatches over at most four window kinds via ``lax.switch``
+    body dispatches over at most five window kinds via ``lax.switch``
     (the kind index is host data shared by every lane, so the branch
     survives a vmapped sweep):
 
       fast    the whole window as one ``fast_window`` call;
       full    whole-window per-step replay (wide event spans, and
               partial tail windows with faults);
+      lean    whole-window per-step replay through the cond-free row
+              body (``step(..., lean=True)``); compiled wherever full
+              is, so whether a window is lean never enters the key;
       hoist   fast prefix -> one hoisted scan tick -> fast suffix, with
               *zero* per-step rows — the AutoNUMA-cadence fast path;
       split   fast prefix -> per-step replay of the (narrow) event span
@@ -1184,7 +1199,8 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
 
     Each kind runs under ``jax.named_scope("window.<kind>")``, and the
     per-step body's phases under ``step.free``, ``step.scan``,
-    ``step.access`` and ``step.fault`` (``_build_step``), so device ops
+    ``step.access`` and ``step.fault`` (``_build_step``; the lean body
+    has only the last two), so device ops
     carry stable names in a profiler trace; scopes change op metadata
     only.
 
@@ -1216,7 +1232,8 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
             # keeps per-step semantics per lane
             return st2, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), outs)
 
-        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf):
+        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf,
+                      lean=False):
             def per_step_row(s2, xr):
                 va_r, wr_r, fid_r, llc_r, sched_r, fr, sc, hf_r, vl_r = xr
 
@@ -1224,7 +1241,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                          sm, sl):
                     return step(st1, cc1, pc1,
                                 (va1, w1, fid1, llc1, sched1, fr, sc,
-                                 hf_r, vl_r), sm, sl)
+                                 hf_r, vl_r), sm, sl, lean)
                 return jax.vmap(lane)(s2, cc, pc, va_r, wr_r, fid_r,
                                       llc_r, sched_r, seg_of_map,
                                       seg_of_leaf)
@@ -1236,9 +1253,10 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
         def run_fast(s, cc, va, wr, llc, vl):
             return fast_window(s, cc, va, wr, llc, vl)
 
-        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf):
+        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf,
+                      lean=False):
             def per_step_row(s2, xr):
-                return step(s2, cc, pc, xr, seg_of_map, seg_of_leaf)
+                return step(s2, cc, pc, xr, seg_of_map, seg_of_leaf, lean)
             return jax.lax.scan(per_step_row, s, arrs)
 
         def run_scan(s, cc, pc, va_row, w_row):
@@ -1279,13 +1297,17 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
         branches = [scoped("window.fast", fast_whole)]
 
         if has_full:
-            def full_replay(s):
-                arrs = (va_w[:block], wr_w[:block], fid_w[:block],
-                        llc_w[:block], sched_w[:block], df_w[:block],
-                        ds_w[:block], hf_w[:block], vl_w[:block])
-                s, o = run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf)
-                return s, pad_rows(o, block)
-            branches.append(scoped("window.full", full_replay))
+            def replay(lean):
+                def run(s):
+                    arrs = (va_w[:block], wr_w[:block], fid_w[:block],
+                            llc_w[:block], sched_w[:block], df_w[:block],
+                            ds_w[:block], hf_w[:block], vl_w[:block])
+                    s, o = run_steps(s, cc, pc, arrs, seg_of_map,
+                                     seg_of_leaf, lean)
+                    return s, pad_rows(o, block)
+                return run
+            branches.append(scoped("window.full", replay(False)))
+            branches.append(scoped("window.lean", replay(True)))
 
         if hoist is not None:
             ph, qh = hoist
@@ -1458,10 +1480,12 @@ def window_tiles(arrays, n_steps: int, block: int,
 
 # Semantic window kinds of the blocked engine's host classification.  The
 # compiled dispatch table only contains the kinds a geometry needs
-# ([fast] + [full][hoist][split], in that order) and ``WindowPlan.kind``
-# stores the *branch index* under that ordering — geometry lives in the
-# compile key, so dispatch table and data can never disagree.
-WIN_FAST, WIN_FULL, WIN_HOIST, WIN_SPLIT = range(4)
+# ([fast] + [full, lean][hoist][split], in that order: lean is compiled
+# wherever full is) and ``WindowPlan.kind`` stores the *branch index*
+# under that ordering — geometry lives in the compile key, so dispatch
+# table and data can never disagree.  ``WindowPlan.counts`` folds lean
+# windows into full.
+WIN_FAST, WIN_FULL, WIN_HOIST, WIN_SPLIT, WIN_LEAN = range(5)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1476,7 +1500,8 @@ class WindowPlan:
     row); ``emit_valid`` (``[n_windows, R_out]`` bool) maps emitted
     output rows back to trace steps in step order; ``counts`` reports
     the semantic classification (fast, full, hoist, split) for
-    telemetry."""
+    telemetry, lean windows counted under full, and ``n_lean`` how many
+    of the full windows run the cond-free lean row body."""
     geom: Optional[tuple]
     kind: np.ndarray
     seg_a: np.ndarray
@@ -1485,6 +1510,7 @@ class WindowPlan:
     rows_in: int
     block: int
     counts: Tuple[int, int, int, int]
+    n_lean: int
 
     @property
     def n_windows(self) -> int:
@@ -1522,7 +1548,12 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
               fault rows: there the span end *is* the trace's last
               faulting step, and letting trace content pick the split
               geometry would fracture the compile-key quantization the
-              broker's shape buckets rely on.
+              broker's shape buckets rely on;
+      lean    a full window with no free, no scan tick, no pad row and
+              a fault on every row (the populate phase): it replays
+              through the cond-free row body.  Lean leaves ``geom``
+              alone — it is compiled wherever full is — so trace
+              content never picks the program.
 
     Segment capacities are per-class maxima rounded up to powers of two
     (``Ph``/``Qh`` hoist prefix/suffix, ``Ps``/``Es``/``Qs`` split
@@ -1559,13 +1590,15 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
         idx = np.flatnonzero(ev[w])
         f, l = int(idx[0]), int(idx[-1])
         if (l - f + 1) > block // 2 or (hf[w].any() and not vl[w].all()):
-            kinds[w] = WIN_FULL
+            lean = (vl[w].all() and hf[w].all()
+                    and not (df[w] | ds[w]).any())
+            kinds[w] = WIN_LEAN if lean else WIN_FULL
         else:
             kinds[w] = WIN_SPLIT
             seg_a[w], seg_b[w] = f, l + 1
             split_rows.append((f, l - f + 1, block - 1 - l))
 
-    has_full = bool((kinds == WIN_FULL).any())
+    has_full = bool(np.isin(kinds, (WIN_FULL, WIN_LEAN)).any())
     hoist_g = (_q2(max(hoist_rows)), _q2(block - min(hoist_rows))) \
         if hoist_rows else None
     split_g = (_q2(max(r[0] for r in split_rows)),
@@ -1575,7 +1608,7 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
         if (has_full or hoist_g or split_g) else None
 
     branch = {WIN_FAST: 0}
-    for k, present in ((WIN_FULL, has_full),
+    for k, present in ((WIN_FULL, has_full), (WIN_LEAN, has_full),
                        (WIN_HOIST, hoist_g is not None),
                        (WIN_SPLIT, split_g is not None)):
         if present:
@@ -1588,7 +1621,7 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
     vlx = np.concatenate([vl, np.zeros_like(vl)], axis=1)
     for w in range(n_w):
         k = int(kinds[w])
-        if k in (WIN_FAST, WIN_FULL):
+        if k in (WIN_FAST, WIN_FULL, WIN_LEAN):
             emit[w, :block] = vl[w]
             continue
         a, b = int(seg_a[w]), int(seg_b[w])
@@ -1604,10 +1637,12 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
                 [pre, mid, vlx[w, b:b + qs]])
     assert int(emit.sum()) == n_steps, \
         f"window plan emits {int(emit.sum())} rows for {n_steps} steps"
+    sem = np.where(kinds == WIN_LEAN, WIN_FULL, kinds)
     return WindowPlan(
         geom=geom, kind=kind, seg_a=seg_a, seg_b=seg_b, emit_valid=emit,
         rows_in=rows_in, block=block,
-        counts=tuple(int((kinds == k).sum()) for k in range(4)))
+        counts=tuple(int((sem == k).sum()) for k in range(4)),
+        n_lean=int((kinds == WIN_LEAN).sum()))
 
 
 def blocked_xs(trace: Trace, mc: MachineConfig, pc: PolicyConfig,
@@ -1721,13 +1756,15 @@ class TieredMemSimulator:
                                   block=block, sched=sched)
             if tel.enabled:
                 # the host-side window classification is exactly the
-                # fast/full/hoist/split dispatch the blocked engine ran
+                # fast/full/hoist/split dispatch the blocked engine ran,
+                # and the lean share of the full windows
                 n_fast, _, n_hoist, n_split = plan.counts
                 tel.counter("sim.windows_event").inc(
                     plan.n_windows - n_fast)
                 tel.counter("sim.windows_fast").inc(n_fast)
                 tel.counter("sim.windows_hoist").inc(n_hoist)
                 tel.counter("sim.windows_split").inc(n_split)
+                tel.counter("sim.windows_lean").inc(plan.n_lean)
             run_all = _compiled_run(mc, budget, self.phase_b, "blocked",
                                     block, group, plan.geom)
             final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,

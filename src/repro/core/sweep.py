@@ -25,7 +25,8 @@ bits before them, so block boundaries stay lane-shared and
 policy-independent).  Event-free windows run as one vectorized
 fast-path step per lane; a window whose only event is a single scan
 tick hoists it between two fast segments; narrow event spans replay
-per-step only inside the span; wide spans replay the whole window.
+per-step only inside the span; wide spans replay the whole window, through
+a cond-free row body where no row frees, ticks or is fault-free.
 Window count depends only on the trace *shape* and the segment
 capacities are pow2-quantized into the compile key
 (``sim.plan_windows``), so the compiled-program quantization the
@@ -235,9 +236,10 @@ def sweep_lanes(mc: MachineConfig,
     tiles) and ``sweep.stage`` (host-to-device copies, initial state,
     shardings, runner lookup); ``sweep.device`` (dispatch to
     ``block_until_ready``, compile included on a cold call) and
-    ``sweep.readback``.  It counts lanes, windows by kind, the rows the
-    window scan covers (``sweep.rows``) and the rows the per-step body
-    replays (``sweep.replay_rows``).  On the device the window kinds and
+    ``sweep.readback``.  It counts lanes, windows by kind (the lean ones
+    among the full, ``sweep.windows_lean``), the rows the window scan
+    covers (``sweep.rows``) and the rows the per-step body replays
+    (``sweep.replay_rows``).  On the device the window kinds and
     step phases carry ``jax.named_scope`` names (``window.*``,
     ``step.*``; see ``sim._build_blocked_body``).  Every hook is
     host-side Python: the compiled program and its outputs are
@@ -398,6 +400,7 @@ def sweep_lanes(mc: MachineConfig,
                 tel.counter("sweep.windows_fast").inc(n_fast)
                 tel.counter("sweep.windows_hoist").inc(n_hoist)
                 tel.counter("sweep.windows_split").inc(n_split)
+                tel.counter("sweep.windows_lean").inc(plan.n_lean)
                 tel.counter("sweep.rows").inc(plan.n_windows * eff_block)
                 tel.counter("sweep.replay_rows").inc(plan.replay_rows)
             else:

@@ -8,8 +8,29 @@ belong in it — nor rewrite the committed artifacts beside it.  Redirect
 the artifact directory and the history sink to per-test temp paths for
 every test; tests that want the real committed history (the green-path
 gate test) read it by explicit path.
+
+The program keeps every compiled runner for the life of the process
+(``sim._RUN_CACHE``, ``sweep._SWEEP_CACHE`` and JAX's own caches), and
+on the CPU each compiled kernel holds its own memory mappings.  A test
+worker that runs several files would pile up the mappings of every
+file's programs and reach the kernel's per-process mapping limit, which
+kills the worker inside a compile; so the compiled programs are released
+after each test file.
 """
+import gc
+import importlib
+
+import jax
 import pytest
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    yield
+    importlib.import_module("repro.core.sim")._RUN_CACHE.clear()
+    importlib.import_module("repro.core.sweep")._SWEEP_CACHE.clear()
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture(autouse=True)

@@ -12,6 +12,7 @@ per-step order.  Same for the conflict-group-compacted allocator scan
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (CostConfig, MachineConfig, PolicyConfig,
                         TieredMemSimulator, Trace, sweep,
@@ -19,6 +20,7 @@ from repro.core import (CostConfig, MachineConfig, PolicyConfig,
                         PT_FOLLOW_DATA)
 from repro.core import alloc as alloc_mod
 from repro.core.ref import OracleSim
+from repro.obs import Telemetry
 from repro.core.sim import (DEFAULT_BLOCK, SCHED_WINNER, blocked_xs,
                             fault_group_bound, fault_schedule, plan_windows,
                             pow2ceil)
@@ -332,3 +334,80 @@ def test_fault_group_bound_and_block_quantization():
     assert bound == max(int(winners.max()), 1)
     assert pow2ceil(5) == 8 and pow2ceil(8) == 8 and pow2ceil(0) == 1
     assert DEFAULT_BLOCK == 64
+
+
+LEAN_PERIOD = 48
+
+
+def populate_mix_trace(mc, steps=112):
+    """Block 16, AutoNUMA period 48: rows 0-79 fault on every row (each
+    thread touches a fresh granule), rows 80+ re-access the populated
+    pool.  Windows 0, 2 and 4 are lean (faults on every row, no free, no
+    tick); window 1 also frees segment 0 (row 20) and window 3 holds the
+    tick at row 48, so both replay through the general full body; window
+    5 is fast and window 6 hoists the tick at row 96.  The first 64
+    granules lie in segment 0, so the free unmaps them."""
+    T = mc.n_threads
+    pop_rows = 80
+    base = mc.n_map // 2 - 16 * T
+    va = np.empty((steps, T), np.int64)
+    va[:pop_rows] = base + np.arange(pop_rows * T).reshape(pop_rows, T)
+    rng = np.random.default_rng(17)
+    va[pop_rows:] = base + rng.integers(16 * T, pop_rows * T,
+                                        (steps - pop_rows, T))
+    return make_trace(mc, (va << mc.map_shift).astype(np.int32), free_at=20)
+
+
+LEAN_POLICIES = [
+    PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
+                 autonuma=True, autonuma_period=LEAN_PERIOD,
+                 autonuma_budget=32),
+    PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_BIND_HIGH, mig=True,
+                 autonuma=True, autonuma_period=LEAN_PERIOD,
+                 autonuma_budget=32),
+    PolicyConfig(data_policy=INTERLEAVE, pt_policy=PT_BIND_ALL,
+                 autonuma=False, autonuma_period=LEAN_PERIOD),
+]
+
+
+@pytest.mark.parametrize("runner", ["solo", "lanes"])
+def test_lean_windows_bitwise(runner):
+    """Lean windows (populate rows through the cond-free row body) and
+    general full windows (one frees, one ticks) in one trace: the blocked
+    engine equals the per-step reference leaf for leaf and timeline bit
+    for bit, solo and as a lane sweep, and the lean counters say three
+    windows took the lean body."""
+    mc = tiny_machine()
+    cc = CostConfig()
+    trace = populate_mix_trace(mc)
+    _, plan = blocked_xs(trace, mc, LEAN_POLICIES[0], block=16)
+    assert plan.n_lean == 3
+    assert plan.counts == (1, 5, 1, 0)
+    _, plan = blocked_xs(trace, mc, LEAN_POLICIES[2], block=16)
+    assert plan.n_lean == 4
+    tel = Telemetry()
+    if runner == "solo":
+        got = [TieredMemSimulator(mc=mc, cc=cc, pc=pc, block=16,
+                                  telemetry=tel).run(trace)
+               for pc in LEAN_POLICIES]
+        ref = [TieredMemSimulator(mc=mc, cc=cc, pc=pc, engine="per_step",
+                                  debug=True).run(trace)
+               for pc in LEAN_POLICIES]
+        # without AutoNUMA window 3 has no tick and is lean too
+        assert tel.metrics.value("sim.windows_lean") == 3 + 3 + 4
+    else:
+        got = sweep(mc, cc, LEAN_POLICIES, trace, block=16, telemetry=tel)
+        ref = sweep(mc, cc, LEAN_POLICIES, trace, engine="per_step",
+                    debug=True)
+        # the lanes share the union schedule: the AutoNUMA lanes' tick
+        # keeps window 3 full for every lane
+        assert tel.metrics.value("sweep.windows_lean") == 3
+    for pc, a, b in zip(LEAN_POLICIES, got, ref):
+        assert_states_bitwise(a.final_state, b.final_state, pc.label())
+        assert sorted(a.timeline) == sorted(b.timeline)
+        for k in a.timeline:
+            np.testing.assert_array_equal(a.timeline[k], b.timeline[k],
+                                          err_msg=f"{pc.label()}: tl/{k}")
+        assert (a.trace_name, a.policy_label) \
+            == (b.trace_name, b.policy_label)
+    assert got[0].summary()["faults"] > 0

@@ -460,28 +460,35 @@ def test_burst_pad_lanes_ratio_counted():
 # ---------------------------------------------------------------------------
 # device scopes and the profiler sink
 # ---------------------------------------------------------------------------
-def scoped_trace(mc, mixed):
+def scoped_trace(mc, case):
     """64 steps for block 16 and scan period 16.  ``mixed``: window 0
     holds a narrow populate span (split), windows 1 and 3 a lone scan tick
     (hoist), window 2 a tick plus faults 13 rows apart (full); the other
-    rows re-access the populated pool.  Otherwise the threads stay idle
-    and, with AutoNUMA off, every window is fast."""
+    rows re-access the populated pool.  ``lean``: window 0 faults on every
+    row (lean), windows 1-3 re-access the pool around a lone tick each
+    (hoist).  Otherwise the threads stay idle and, with AutoNUMA off,
+    every window is fast."""
     from test_blocked import make_trace
     T = mc.n_threads
-    if not mixed:
+    if case == "all-fast":
         return make_trace(mc, np.full((64, T), -1, np.int32))
     rng = np.random.default_rng(7)
-    va = rng.integers(0, 4 * T, (64, T)).astype(np.int64)
-    va[:4] = np.arange(4 * T).reshape(4, T)
-    va[33, 0], va[45, 0] = 4 * T, 4 * T + 1
+    if case == "lean":
+        va = rng.integers(0, 16 * T, (64, T)).astype(np.int64)
+        va[:16] = np.arange(16 * T).reshape(16, T)
+    else:
+        va = rng.integers(0, 4 * T, (64, T)).astype(np.int64)
+        va[:4] = np.arange(4 * T).reshape(4, T)
+        va[33, 0], va[45, 0] = 4 * T, 4 * T + 1
     return make_trace(mc, (va << mc.map_shift).astype(np.int32))
 
 
-@pytest.mark.parametrize("mixed", [False, True], ids=["all-fast", "mixed"])
-def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, mixed):
+@pytest.mark.parametrize("case", ["all-fast", "mixed", "lean"])
+def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, case):
     """The compiled lane sweep names each window kind its geometry
-    compiles (``window.*``) and, where it compiles the per-step body, each
-    step phase (``step.*``) in its ops' metadata."""
+    compiles (``window.*``: lean wherever full) and, where it compiles
+    the per-step body, each step phase (``step.*``) in its ops' metadata;
+    the lean row body holds only ``step.access`` and ``step.fault``."""
     import importlib
     import re
     from test_blocked import tiny_machine as blocked_machine
@@ -489,7 +496,8 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, mixed):
 
     mc = blocked_machine()
     pc = PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
-                      autonuma=mixed, autonuma_period=16, autonuma_budget=32)
+                      autonuma=case != "all-fast", autonuma_period=16,
+                      autonuma_budget=32)
     texts = []
     real = sweep_mod._sweep_runner
 
@@ -503,22 +511,31 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, mixed):
 
     monkeypatch.setattr(sweep_mod, "_sweep_runner", spy)
     tel = Telemetry()
-    sweep_lanes(mc, [CostConfig()], [pc], [scoped_trace(mc, mixed)],
+    sweep_lanes(mc, [CostConfig()], [pc], [scoped_trace(mc, case)],
                 block=16, telemetry=tel)
     m = tel.metrics
     kinds = {"window.fast": m.value("sweep.windows_fast"),
              "window.full": (m.value("sweep.windows_event")
                              - m.value("sweep.windows_hoist")
-                             - m.value("sweep.windows_split")),
+                             - m.value("sweep.windows_split")
+                             - m.value("sweep.windows_lean")),
+             "window.lean": m.value("sweep.windows_lean"),
              "window.hoist": m.value("sweep.windows_hoist"),
              "window.split": m.value("sweep.windows_split")}
-    if mixed:
+    if case == "mixed":
         assert kinds == {"window.fast": 0, "window.full": 1,
-                         "window.hoist": 2, "window.split": 1}
+                         "window.lean": 0, "window.hoist": 2,
+                         "window.split": 1}
         assert m.value("sweep.replay_rows") == 16 + 4
+    elif case == "lean":
+        assert kinds == {"window.fast": 0, "window.full": 0,
+                         "window.lean": 1, "window.hoist": 3,
+                         "window.split": 0}
+        assert m.value("sweep.replay_rows") == 16
     else:
         assert kinds == {"window.fast": 4, "window.full": 0,
-                         "window.hoist": 0, "window.split": 0}
+                         "window.lean": 0, "window.hoist": 0,
+                         "window.split": 0}
         assert m.value("sweep.replay_rows") == 0
     assert m.value("sweep.rows") == 64
     (text,) = texts
@@ -526,9 +543,17 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, mixed):
     scopes = set(re.findall(r"[/(]((?:window|step)\.[a-z]+)[/)]", text))
     # fast is always compiled: it is branch 0 of every geometry
     want = {"window.fast"} | {k for k, n in kinds.items() if n}
-    if kinds["window.full"] or kinds["window.split"]:
+    if kinds["window.full"] or kinds["window.lean"]:
+        want |= {"window.full", "window.lean"}
+    if want & {"window.full", "window.split"}:
         want |= {"step.free", "step.scan", "step.access", "step.fault"}
     assert scopes == want
+    # the step phases each window kind's ops are named under
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    lean_steps = {st for p in paths if "window.lean" in p
+                  for st in re.findall(r"step\.[a-z]+", p)}
+    assert lean_steps == ({"step.access", "step.fault"}
+                          if "window.lean" in want else set())
 
 
 def read_host_spans(trace_dir):

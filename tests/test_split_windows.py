@@ -83,6 +83,49 @@ def test_partial_tail_with_faults_replays_full():
     assert int(p.emit_valid.sum()) == S
 
 
+def lean_case_masks(case):
+    """Window 1 (rows 16-31) of a 64-step, block-16 trace faults on every
+    row; ``case`` adds the one thing that keeps it out of the lean body.
+    ``tail`` instead puts the all-fault rows in the partial tail window
+    of a 56-step trace."""
+    S = 56 if case == "tail" else 64
+    df, ds, hf = quiet_masks(S)
+    if case == "tail":
+        hf[48:] = True
+        return df, ds, hf, S
+    hf[16:32] = True
+    if case == "tick":
+        ds[21] = True
+    elif case == "free":
+        df[21] = True
+    elif case == "fault-free-row":
+        hf[21] = False
+    return df, ds, hf, S
+
+
+@pytest.mark.parametrize("case,lean", [
+    ("lean", True), ("tick", False), ("free", False),
+    ("fault-free-row", False), ("tail", False)])
+def test_plan_marks_lean_windows(case, lean):
+    """A full window with no free, no scan tick, no pad row and a fault on
+    every row is lean; anything else keeps the general full body.  Lean
+    moves only the branch index: ``counts`` (lean under full) and ``geom``
+    are what the planner gave before lean existed, so the compile key
+    never depends on it."""
+    df, ds, hf, S = lean_case_masks(case)
+    p = plan_windows(df, ds, hf, S, 16)
+    w = 3 if case == "tail" else 1
+    assert p.n_lean == int(lean)
+    assert p.counts == (3, 1, 0, 0)
+    assert p.geom == (True, None, None)
+    assert p.rows_in == 16
+    assert int(p.emit_valid.sum()) == S
+    # branches [fast, full, lean]: fast 0, full 1, lean 2
+    want = np.zeros(p.n_windows, np.int32)
+    want[w] = 2 if lean else 1
+    np.testing.assert_array_equal(p.kind, want)
+
+
 def test_geometry_quantizes_to_pow2_buckets():
     S, B = 64, 16
 
