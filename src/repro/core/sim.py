@@ -213,7 +213,9 @@ def fault_schedule(tr: Trace, mc: MachineConfig) -> np.ndarray:
     the bits over-approximate, and the device gates every request on its
     per-thread OOM latch (``alloc_many``'s ``gate``), under which the
     lane is inert anyway.  Results are memoized on a digest of the trace
-    contents — figures sharing padded traces pay the host pass once.
+    contents — figures sharing padded traces pay the host pass once.  The
+    schedule comes from first touches, one array pass per stretch between
+    segment frees (:func:`_first_touch_schedule`).
     """
     shift, n_map, rb = mc.map_shift, mc.n_map, mc.radix_bits
     n_leaf, n_mid, n_top = mc.n_leaf_pages, mc.n_mid_pages, mc.n_top_pages
@@ -229,52 +231,65 @@ def fault_schedule(tr: Trace, mc: MachineConfig) -> np.ndarray:
         _SCHED_CACHE.move_to_end(key)
         return hit
 
-    leaf_first = (np.arange(n_leaf, dtype=np.int64) << rb) % max(n_map, 1)
-    seg_of_leaf = seg[leaf_first]
-    mapped = np.zeros(n_map, bool)
-    exists = {  # PT-entry existence per level (mid/top/root are never freed)
-        "root": np.zeros(1, bool), "top": np.zeros(n_top, bool),
-        "mid": np.zeros(n_mid, bool), "leaf": np.zeros(n_leaf, bool),
-    }
-    S, T = va.shape
-    sched = np.zeros((S, T), np.uint8)
-    for s in range(S):
-        if free_seg[s] >= 0:
-            mapped[seg == free_seg[s]] = False
-            exists["leaf"][seg_of_leaf == free_seg[s]] = False
-        row = va[s]
-        act = row >= 0
-        if not act.any():
-            continue
-        m = np.clip(row.astype(np.int64) >> shift, 0, n_map - 1)
-        do = act & ~mapped[m]
-        if not do.any():
-            continue
-        sched[s] |= np.where(do, SCHED_DO, np.uint8(0))
-        do_t = np.where(do)[0]                       # ascending thread order
-        _, first = np.unique(m[do_t], return_index=True)
-        wt = np.sort(do_t[first])                    # first thread per granule
-        sched[s, wt] |= SCHED_WINNER
-        mw = m[wt]
-        levels = (
-            (SCHED_NEED_ROOT, "root", np.zeros(len(wt), np.int64)),
-            (SCHED_NEED_TOP, "top", np.clip(mw >> (3 * rb), 0, n_top - 1)),
-            (SCHED_NEED_MID, "mid", np.clip(mw >> (2 * rb), 0, n_mid - 1)),
-            (SCHED_NEED_LEAF, "leaf", mw >> rb),
-        )
-        for bit, lvl, e in levels:
-            miss = ~exists[lvl][e]
-            if not miss.any():
-                continue
-            em, tm = e[miss], wt[miss]
-            uniq, fidx = np.unique(em, return_index=True)
-            sched[s, tm[fidx]] |= bit
-            exists[lvl][uniq] = True
-        mapped[mw] = True
+    sched = _first_touch_schedule(va, seg, free_seg, mc)
     _SCHED_CACHE[key] = sched
     while len(_SCHED_CACHE) > _SCHED_CACHE_MAX:
         _SCHED_CACHE.popitem(last=False)
     return sched
+
+
+def _first_touch_schedule(va: np.ndarray, seg: np.ndarray,
+                          free_seg: np.ndarray,
+                          mc: MachineConfig) -> np.ndarray:
+    """:func:`fault_schedule` from first touches, one array pass per
+    stretch of steps between segment frees.  Within a stretch mapped-ness
+    and PT-entry existence only grow, so a thread faults exactly where a
+    granule unmapped at the stretch's start is touched for the first time
+    in it (in (step, thread) order); the first such touch is the granule's
+    winner, and the first winner under each missing root/top/mid/leaf
+    entry allocates it.  A free, applied before its step's accesses,
+    unmaps its segment's granules and leaf entries."""
+    shift, n_map, rb = mc.map_shift, mc.n_map, mc.radix_bits
+    n_leaf, n_mid, n_top = mc.n_leaf_pages, mc.n_mid_pages, mc.n_top_pages
+    leaf_first = (np.arange(n_leaf, dtype=np.int64) << rb) % max(n_map, 1)
+    seg_of_leaf = seg[leaf_first]
+    mapped = np.zeros(n_map, bool)
+    # PT-entry existence per level, root first (only leaves are freed)
+    exists = (np.zeros(1, bool), np.zeros(n_top, bool),
+              np.zeros(n_mid, bool), np.zeros(n_leaf, bool))
+    S, T = va.shape
+    sched = np.zeros(S * T, np.uint8)
+    flat = va.reshape(-1)
+    starts = np.union1d([0], np.flatnonzero(free_seg >= 0))
+    for a, b in zip(starts, np.append(starts[1:], S)):
+        if free_seg[a] >= 0:
+            mapped[seg == free_seg[a]] = False
+            exists[3][seg_of_leaf == free_seg[a]] = False
+        pos = a * T + np.flatnonzero(flat[a * T:b * T] >= 0)
+        m = np.clip(flat[pos].astype(np.int64) >> shift, 0, n_map - 1)
+        cold = ~mapped[m]
+        pos, m = pos[cold], m[cold]
+        if not len(pos):
+            continue
+        g, first, inv = np.unique(m, return_index=True, return_inverse=True)
+        win = pos[first]                            # each granule's winner
+        sched[pos[(win // T)[inv] == pos // T]] |= SCHED_DO
+        order = np.argsort(win)
+        win, g = win[order], g[order]
+        sched[win] |= SCHED_WINNER
+        for bit, ex, e in (
+                (SCHED_NEED_ROOT, exists[0], np.zeros(len(g), np.int64)),
+                (SCHED_NEED_TOP, exists[1], np.clip(g >> (3 * rb), 0,
+                                                    n_top - 1)),
+                (SCHED_NEED_MID, exists[2], np.clip(g >> (2 * rb), 0,
+                                                    n_mid - 1)),
+                (SCHED_NEED_LEAF, exists[3], g >> rb)):
+            miss = ~ex[e]
+            uniq, f = np.unique(e[miss], return_index=True)
+            sched[win[miss][f]] |= bit
+            ex[uniq] = True
+        mapped[g] = True
+    return sched.reshape(S, T)
 
 
 def fault_step_mask(tr: Trace, mc: MachineConfig) -> np.ndarray:
@@ -1200,9 +1215,10 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
     Each kind runs under ``jax.named_scope("window.<kind>")``, and the
     per-step body's phases under ``step.free``, ``step.scan``,
     ``step.access`` and ``step.fault`` (``_build_step``; the lean body
-    has only the last two), so device ops
-    carry stable names in a profiler trace; scopes change op metadata
-    only.
+    has only the last two), and a hoisted scan tick under ``mig.scan``
+    inside ``window.hoist`` (a replayed tick stays ``step.scan``), so
+    device ops carry stable names in a profiler trace; scopes change op
+    metadata only.
 
     Segment capacities come from ``geom``; each segment's live length
     arrives as traced offsets (``a_idx``/``b_idx``) and is enforced
@@ -1248,7 +1264,8 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
             return jax.lax.scan(per_step_row, s, arrs)
 
         def run_scan(s, cc, pc, va_row, w_row):
-            return jax.vmap(scan_op)(s, cc, pc, va_row, w_row)
+            with jax.named_scope("mig.scan"):
+                return jax.vmap(scan_op)(s, cc, pc, va_row, w_row)
     else:
         def run_fast(s, cc, va, wr, llc, vl):
             return fast_window(s, cc, va, wr, llc, vl)
@@ -1260,7 +1277,8 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
             return jax.lax.scan(per_step_row, s, arrs)
 
         def run_scan(s, cc, pc, va_row, w_row):
-            return scan_op(s, cc, pc, va_row, w_row)
+            with jax.named_scope("mig.scan"):
+                return scan_op(s, cc, pc, va_row, w_row)
 
     def dsl(a, start, size):
         return jax.lax.dynamic_slice_in_dim(a, start, size, axis=0)
@@ -1500,8 +1518,10 @@ class WindowPlan:
     row); ``emit_valid`` (``[n_windows, R_out]`` bool) maps emitted
     output rows back to trace steps in step order; ``counts`` reports
     the semantic classification (fast, full, hoist, split) for
-    telemetry, lean windows counted under full, and ``n_lean`` how many
-    of the full windows run the cond-free lean row body."""
+    telemetry, lean windows counted under full, ``n_lean`` how many
+    of the full windows run the cond-free lean row body, and
+    ``scan_ticks`` the scan-tick rows as (hoisted, replayed): one a hoist
+    window, and those inside full and split windows."""
     geom: Optional[tuple]
     kind: np.ndarray
     seg_a: np.ndarray
@@ -1511,6 +1531,7 @@ class WindowPlan:
     block: int
     counts: Tuple[int, int, int, int]
     n_lean: int
+    scan_ticks: Tuple[int, int]
 
     @property
     def n_windows(self) -> int:
@@ -1642,7 +1663,8 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
         geom=geom, kind=kind, seg_a=seg_a, seg_b=seg_b, emit_valid=emit,
         rows_in=rows_in, block=block,
         counts=tuple(int((sem == k).sum()) for k in range(4)),
-        n_lean=int((kinds == WIN_LEAN).sum()))
+        n_lean=int((kinds == WIN_LEAN).sum()),
+        scan_ticks=(len(hoist_rows), int(ds[kinds != WIN_HOIST].sum())))
 
 
 def blocked_xs(trace: Trace, mc: MachineConfig, pc: PolicyConfig,
@@ -1765,6 +1787,9 @@ class TieredMemSimulator:
                 tel.counter("sim.windows_hoist").inc(n_hoist)
                 tel.counter("sim.windows_split").inc(n_split)
                 tel.counter("sim.windows_lean").inc(plan.n_lean)
+                hoisted, replayed = plan.scan_ticks
+                tel.counter("sim.scan_ticks", arm="hoist").inc(hoisted)
+                tel.counter("sim.scan_ticks", arm="replay").inc(replayed)
             run_all = _compiled_run(mc, budget, self.phase_b, "blocked",
                                     block, group, plan.geom)
             final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,

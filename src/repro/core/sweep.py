@@ -238,10 +238,12 @@ def sweep_lanes(mc: MachineConfig,
     ``block_until_ready``, compile included on a cold call) and
     ``sweep.readback``.  It counts lanes, windows by kind (the lean ones
     among the full, ``sweep.windows_lean``), the rows the window scan
-    covers (``sweep.rows``) and the rows the per-step body replays
-    (``sweep.replay_rows``).  On the device the window kinds and
-    step phases carry ``jax.named_scope`` names (``window.*``,
-    ``step.*``; see ``sim._build_blocked_body``).  Every hook is
+    covers (``sweep.rows``), the rows the per-step body replays
+    (``sweep.replay_rows``) and the scan ticks by whether they run
+    hoisted or replayed (``sweep.scan_ticks{arm=hoist|replay}``).  On the
+    device the window kinds, step phases and hoisted scan ticks carry
+    ``jax.named_scope`` names (``window.*``, ``step.*``, ``mig.scan``;
+    see ``sim._build_blocked_body``).  Every hook is
     host-side Python: the compiled program and its outputs are
     bitwise-identical with telemetry on or off.
     """
@@ -403,6 +405,9 @@ def sweep_lanes(mc: MachineConfig,
                 tel.counter("sweep.windows_lean").inc(plan.n_lean)
                 tel.counter("sweep.rows").inc(plan.n_windows * eff_block)
                 tel.counter("sweep.replay_rows").inc(plan.replay_rows)
+                hoisted, replayed = plan.scan_ticks
+                tel.counter("sweep.scan_ticks", arm="hoist").inc(hoisted)
+                tel.counter("sweep.scan_ticks", arm="replay").inc(replayed)
             else:
                 tel.counter("sweep.steps").inc(S)
                 tel.counter("sweep.rows").inc(S)

@@ -17,12 +17,16 @@ Four lock points:
    ``tests/test_memsys.py`` pattern).
 4. **Fault-schedule invariants** — the host conflict model holds under
    N-tier machines: DO bits equal an independent mapped-ness replay,
-   exactly one WINNER per (step, granule), and every bit is monotone in
-   the trace prefix (``fault_schedule(tr[:k]) == fault_schedule(tr)[:k]``).
+   exactly one WINNER per (step, granule), every bit is monotone in the
+   trace prefix (``fault_schedule(tr[:k]) == fault_schedule(tr)[:k]``),
+   and the first-touch schedule equals a step-by-step replay bit for bit,
+   frees included.
 
 Plus the reference-path gate: ``engine="per_step"`` / ``phase_b=
 "sequential"`` are debug-only everywhere (simulator, sweep, service).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -425,6 +429,80 @@ def test_fault_schedule_invariants(seed):
     for k in (1, 37, 64, 100, 128):
         np.testing.assert_array_equal(fault_schedule(prefix_trace(tr, k), mc),
                                       sched[:k], err_msg=f"prefix {k}")
+
+
+def replay_schedule(tr, mc):
+    """Step-by-step replay of the host conflict model: the schedule
+    ``fault_schedule`` gives, one step at a time, frees applied before
+    the step's accesses (they unmap the segment's granules and leaf
+    entries; root, top and mid entries are never freed)."""
+    rb, n_map = mc.radix_bits, mc.n_map
+    va = np.asarray(tr.va)
+    seg = np.asarray(tr.seg_of_map)
+    free_seg = np.asarray(tr.free_seg)
+    seg_of_leaf = seg[(np.arange(mc.n_leaf_pages, dtype=np.int64) << rb)
+                      % max(n_map, 1)]
+    mapped = np.zeros(n_map, bool)
+    exists = [np.zeros(1, bool), np.zeros(mc.n_top_pages, bool),
+              np.zeros(mc.n_mid_pages, bool), np.zeros(mc.n_leaf_pages, bool)]
+    bits = (SCHED_NEED_ROOT, SCHED_NEED_TOP, SCHED_NEED_MID, SCHED_NEED_LEAF)
+    sched = np.zeros(va.shape, np.uint8)
+    for s in range(va.shape[0]):
+        if free_seg[s] >= 0:
+            mapped[seg == free_seg[s]] = False
+            exists[3][seg_of_leaf == free_seg[s]] = False
+        winners = {}
+        for t in range(va.shape[1]):
+            if va[s, t] < 0:
+                continue
+            m = min(int(va[s, t]) >> mc.map_shift, n_map - 1)
+            if not mapped[m]:
+                sched[s, t] |= SCHED_DO
+                winners.setdefault(m, t)
+        for m, t in sorted(winners.items(), key=lambda mt: mt[1]):
+            sched[s, t] |= SCHED_WINNER
+            for lvl, e in enumerate((0, min(m >> 3 * rb, mc.n_top_pages - 1),
+                                     min(m >> 2 * rb, mc.n_mid_pages - 1),
+                                     m >> rb)):
+                if not exists[lvl][e]:
+                    sched[s, t] |= bits[lvl]
+                    exists[lvl][e] = True
+        for m in winners:
+            mapped[m] = True
+    return sched
+
+
+@pytest.mark.parametrize("case", ["3tier", "thp", "crowded", "frees",
+                                  "crowded_frees"])
+def test_first_touch_schedule_equals_replay(case):
+    """The schedule built from first touches, one array pass per stretch
+    between frees, is a step-by-step replay's bit for bit, and its DO
+    bits are an independent replay's miss set.  ``crowded``: every thread
+    on eight pages, so most steps hold several threads per granule;
+    ``frees``: segment 0 freed at steps 0, 40, 41 and 90, so granules and
+    leaf entries fault again."""
+    frees = [0, 40, 41, 90] if "frees" in case else None
+    if case == "3tier":
+        mc = tiny_machine(tiers=TIER3, va_pages=1 << 11)
+        tr = random_trace(mc, steps=128, seed=5)
+    elif case == "thp":
+        mc = tiny_machine(tiers=TIER3, radix_bits=6, page_order=6)
+        tr = random_trace(mc, steps=128, seed=6)
+    elif case == "frees":
+        mc = tiny_machine(tiers=TIER3, va_pages=1 << 11)
+        tr = random_trace(mc, steps=128, seed=8, free_at=frees)
+    else:
+        mc = tiny_machine()
+        tr = random_trace(mc, steps=128, seed=7, free_at=frees)
+        tr = dataclasses.replace(tr, va=np.where(tr.va >= 0, tr.va % 8, -1)
+                                 .astype(np.int32))
+    sched = fault_schedule(tr, mc)
+    np.testing.assert_array_equal(sched, replay_schedule(tr, mc))
+    np.testing.assert_array_equal((sched & SCHED_DO) > 0,
+                                  replay_miss_set(tr, mc))
+    assert (sched & SCHED_NEED_LEAF).any()
+    if frees:       # a freed leaf entry is allocated again after the free
+        assert (sched[frees[1]:] & SCHED_NEED_LEAF).any()
 
 
 # ---------------------------------------------------------------------------

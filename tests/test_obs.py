@@ -15,7 +15,8 @@ Three contracts:
    Perfetto-loadable trace carrying one span per query lifecycle stage
    (admit -> queue -> flush -> sweep -> resolve).
 4. **Device scopes and the profiler sink** — the compiled sweep names its
-   window kinds and step phases (``window.*``, ``step.*``), and a
+   window kinds, step phases and hoisted scan ticks (``window.*``,
+   ``step.*``, ``mig.scan``), and a
    ``jax.profiler`` trace holds each flush's span tree on its host plane,
    every span carrying the flush number.
 """
@@ -488,7 +489,9 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, case):
     """The compiled lane sweep names each window kind its geometry
     compiles (``window.*``: lean wherever full) and, where it compiles
     the per-step body, each step phase (``step.*``) in its ops' metadata;
-    the lean row body holds only ``step.access`` and ``step.fault``."""
+    the lean row body holds only ``step.access`` and ``step.fault``, and
+    the hoisted scan tick runs under ``mig.scan`` inside ``window.hoist``
+    wherever that window compiles (a replayed tick stays ``step.scan``)."""
     import importlib
     import re
     from test_blocked import tiny_machine as blocked_machine
@@ -554,6 +557,41 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, case):
                   for st in re.findall(r"step\.[a-z]+", p)}
     assert lean_steps == ({"step.access", "step.fault"}
                           if "window.lean" in want else set())
+    mig = [p for p in paths if "mig.scan" in p]
+    assert bool(mig) == ("window.hoist" in want)
+    assert all("window.hoist" in p and "step." not in p for p in mig)
+
+
+@pytest.mark.parametrize("entry", ["sweep", "sim"])
+def test_scan_tick_counters_match_the_plan(entry):
+    """``sweep.scan_ticks`` (``sim.scan_ticks`` for a solo run) counts
+    the window plan's scan ticks by arm: in the ``mixed`` trace windows 1
+    and 3 hoist theirs and window 2 replays its tick inside a full
+    window.  Outputs stay bit-identical to telemetry off."""
+    from repro.core.sim import blocked_xs, scan_step_mask
+    from test_blocked import tiny_machine as blocked_machine
+    mc = blocked_machine()
+    pc = PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
+                      autonuma=True, autonuma_period=16, autonuma_budget=32)
+    tr = scoped_trace(mc, "mixed")
+    _, plan = blocked_xs(tr, mc, pc, block=16)
+    assert plan.scan_ticks == (2, 1)
+    assert sum(plan.scan_ticks) == int(scan_step_mask(64, 16).sum())
+
+    tel = Telemetry(tracing=True)
+    if entry == "sweep":
+        plain, = sweep_lanes(mc, [CostConfig()], [pc], [tr], block=16)
+        traced, = sweep_lanes(mc, [CostConfig()], [pc], [tr], block=16,
+                              telemetry=tel)
+    else:
+        plain = TieredMemSimulator(mc=mc, pc=pc, block=16).run(tr)
+        traced = TieredMemSimulator(mc=mc, pc=pc, block=16,
+                                    telemetry=tel).run(tr)
+    assert_bitwise_equal(plain, traced, entry)
+    m = tel.metrics
+    assert (m.value(f"{entry}.scan_ticks", arm="hoist"),
+            m.value(f"{entry}.scan_ticks", arm="replay")) == plan.scan_ticks
+    assert m.value(f"{entry}.windows_hoist") == plan.scan_ticks[0]
 
 
 def read_host_spans(trace_dir):
