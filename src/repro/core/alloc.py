@@ -19,6 +19,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import (MachineConfig, INTERLEAVE, PT_BIND_ALL, PT_BIND_HIGH,
                      PT_FOLLOW_DATA)
@@ -275,3 +276,130 @@ def alloc_many(node_free: jax.Array, node_reclaimable: jax.Array,
     prefix = jnp.cumsum(fail_t.astype(I32)) - fail_t.astype(I32)
     gate = ~oom_killed & (prefix == 0)
     return nodes, slow, ok, act, gate, free, rec, ptr, oom
+
+
+def _exclusive_cumsum(x: jax.Array) -> jax.Array:
+    x = x.astype(I32)
+    return jnp.cumsum(x) - x
+
+
+def _pick(rank: jax.Array, above: jax.Array, has_page: jax.Array,
+          has_reclaim: jax.Array):
+    """``alloc_one``'s choice for many requests at once, over the static
+    node axis.  ``rank[..., n]`` is node n's position in the request's
+    preference order (``_UNLISTED`` where it is not listed); the three
+    class masks are per node.  Returns ``(onehot[..., n], ok, slow,
+    from_reclaim)``: the chosen node as a one-hot row (all False when
+    nothing is acceptable) and ``alloc_one``'s flags."""
+    listed = rank < _UNLISTED
+    classes = (listed & above, listed & has_page, listed & has_reclaim)
+    fast_ok, slow_ok, rec_ok = (jnp.any(c, axis=-1) for c in classes)
+    chosen = jnp.where(fast_ok[..., None], classes[0],
+                       jnp.where(slow_ok[..., None], classes[1], classes[2]))
+    r = jnp.where(chosen, rank, _UNLISTED)
+    onehot = chosen & (r == jnp.min(r, axis=-1, keepdims=True))
+    ok = fast_ok | slow_ok | rec_ok
+    return onehot, ok, ok & ~fast_ok, ok & ~fast_ok & ~slow_ok
+
+
+_UNLISTED = 1 << 20
+
+
+def alloc_vector(node_free: jax.Array, node_reclaimable: jax.Array,
+                 interleave_ptr: jax.Array, oom_killed: jax.Array,
+                 wm: jax.Array, data_policy, pt_policy, mc: MachineConfig,
+                 need_pt: jax.Array, need_data: jax.Array):
+    """``alloc_many`` in one vector pass, exact wherever no node's
+    allocator class changes inside the row.
+
+    Every pick of ``alloc_one`` depends on the carry only through three
+    bits per node: ``free > wm``, ``free > 0`` and ``reclaimable > 0``.
+    Taking them from the row-start carry, each request's outcome follows
+    without the scan: whether it succeeds depends on the bits alone
+    (every preference order of a request lists the same nodes, whatever
+    the interleave cursor), so the thread-order OOM gate is a prefix over
+    failing threads; the cursor of each request is the start cursor plus
+    the advances before it; and its node is the first listed node of the
+    best class.  The counts only fall inside a row, so if the bits after
+    the whole row's demand equal the bits at its start they held at every
+    request, and every output equals ``alloc_many``'s full-depth scan bit
+    for bit.  That is the returned ``verified``; where it is False the
+    caller must run the scan instead.
+
+    Returns ``alloc_many``'s tuple followed by ``verified``.
+    """
+    data_policy = jnp.asarray(data_policy)
+    pt_policy = jnp.asarray(pt_policy)
+    T = need_data.shape[0]
+    n = mc.n_nodes
+    thp = mc.page_order > 0
+    is_interleave = data_policy == INTERLEAVE
+    is_bhi = pt_policy == PT_BIND_HIGH
+    node = jnp.arange(n, dtype=I32)
+
+    above = node_free > wm
+    has_page = node_free > 0
+    has_reclaim = node_reclaimable > 0
+
+    # Static per level: BHi binds the upper levels (and the THP leaf), and
+    # those fall back to the data policy when DRAM is exhausted.
+    fb_level = jnp.asarray([u or thp for u in _LEVEL_IS_UPPER] + [False])
+    bound = ((pt_policy == PT_BIND_ALL) & (jnp.arange(5) < 4)) | \
+        (is_bhi & fb_level)                                      # [5]
+
+    alloc_pos = np.full((n,), -1, np.int64)
+    alloc_pos[list(mc.alloc_nodes)] = np.arange(len(mc.alloc_nodes))
+    alloc_pos = jnp.asarray(alloc_pos, I32)
+    a = len(mc.alloc_nodes)
+    data_listed = jnp.where(is_interleave, alloc_pos >= 0, True)  # [n]
+    dram_listed = node < 2
+
+    # Success is cursor-free, so the OOM gate comes first.
+    def any_class(listed, wm_bits):
+        return jnp.any(listed & (wm_bits | has_page | has_reclaim))
+    ok_data = any_class(data_listed, above)
+    ok_level = jnp.where(bound, any_class(dram_listed, has_page), ok_data)
+    ok_level = ok_level | (fb_level & is_bhi & ok_data)          # [5]
+    need = jnp.concatenate([need_pt, need_data[:, None]], axis=1)
+    fail_t = jnp.any(need & ~ok_level, axis=1)
+    gate = ~oom_killed & (_exclusive_cumsum(fail_t) == 0)
+    act = need & gate[:, None]
+    ok = jnp.broadcast_to(ok_level, (T, 5))
+    do = act & ok
+
+    # The interleave cursor each request sees.
+    pt_adv = (pt_policy == PT_FOLLOW_DATA) & is_interleave
+    adv = do & jnp.where(jnp.arange(5) < 4, pt_adv, is_interleave)
+    cursor = interleave_ptr + _exclusive_cumsum(adv.ravel()).reshape(T, 5)
+
+    # Preference ranks [T, 5, n]: first touch by the thread's socket,
+    # interleave rotated to the cursor, PT binds local DRAM first.
+    local = jnp.where(jnp.arange(T) < mc.n_threads // 2, 0, 1)[:, None, None]
+    remote = (node % 2 != local).astype(I32)
+    ft_rank = 2 * (node // 2) + remote
+    il_rank = jnp.where(alloc_pos >= 0,
+                        (alloc_pos - (cursor % a)[..., None]) % a, _UNLISTED)
+    data_rank = jnp.where(is_interleave, il_rank, ft_rank)
+    dram_rank = jnp.where(dram_listed, remote, _UNLISTED)
+    b3 = bound[:, None]
+    rank = jnp.where(b3, dram_rank, data_rank)
+    onehot, ok_p, slow, from_rec = _pick(
+        rank, jnp.where(b3, has_page, above), has_page, has_reclaim)
+    fb_onehot, _, fb_slow, fb_rec = _pick(data_rank, above, has_page,
+                                          has_reclaim)
+    use_fb = (fb_level & is_bhi) & ~ok_p
+    onehot = jnp.where(use_fb[..., None], fb_onehot, onehot)
+    slow = jnp.where(use_fb, fb_slow, slow)
+    from_rec = jnp.where(use_fb, fb_rec, from_rec)
+    nodes = jnp.where(ok, jnp.sum(jnp.where(onehot, node, 0), axis=-1), -1)
+
+    taken = onehot & do[..., None]
+    d_free = jnp.sum(taken & ~from_rec[..., None], axis=(0, 1), dtype=I32)
+    d_rec = jnp.sum(taken & from_rec[..., None], axis=(0, 1), dtype=I32)
+    free = node_free - d_free
+    rec = node_reclaimable - d_rec
+    verified = jnp.all((above == (free > wm)) & (has_page == (free > 0))
+                       & (has_reclaim == (rec > 0)))
+    ptr = interleave_ptr + jnp.sum(adv, dtype=I32)
+    oom = oom_killed | jnp.any(act & ~ok)
+    return nodes, slow, ok, act, gate, free, rec, ptr, oom, verified

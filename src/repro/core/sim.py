@@ -24,17 +24,23 @@ One ``lax.scan`` step simulates one memory access per CPU thread:
                cross-thread dependency besides the allocator counters,
                and both are trace-derivable (mapped-ness and PT-entry
                existence are policy-independent).
-            2. Device-side, ``alloc.alloc_many`` serializes *only* the
-               allocator counters (``node_free`` / ``node_reclaimable`` /
+            2. Device-side, ``alloc.alloc_vector`` hands out the
+               whole row's pages in one vector pass from the row-start
+               allocator classes of each node, and says whether that is
+               exact (no node crosses its watermark, runs dry or exhausts
+               its reclaim reserve inside the row).  Where it is not, in
+               any lane, ``alloc.alloc_many`` serializes the allocator
+               counters (``node_free`` / ``node_reclaimable`` /
                ``interleave_ptr`` / the OOM latch, ~10 scalars) through a
-               tiny ``lax.scan`` over threads; every heavy update — PT
-               placement scatters, per-thread TLB fills, cycle and event
-               counters — then commits vectorized across all threads at
-               once.  The result is bit-identical (placements, counters;
-               cycles to f32 rounding) to the retained sequential
-               ``fori_loop`` path (``phase_b="sequential"``) and to the
-               pure-Python oracle; ``tests/test_fault_batch.py`` enforces
-               all three pairings.
+               tiny ``lax.scan`` over threads instead — one scalar
+               ``lax.cond`` per row, outside the lane vmap.  Every heavy
+               update — PT placement scatters, per-thread TLB fills,
+               cycle and event counters — then commits vectorized across
+               all threads at once.  The result is bit-identical
+               (placements, counters; cycles to f32 rounding) to the
+               retained sequential ``fori_loop`` path
+               (``phase_b="sequential"``) and to the pure-Python oracle;
+               ``tests/test_fault_batch.py`` enforces all three pairings.
 
             Under a vmapped policy sweep the old per-thread ``lax.cond``
             lowered to a select that ran the fault handler for every
@@ -94,6 +100,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, Optional, Tuple
 
@@ -712,34 +719,27 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
         return st, cc, pc, va_row, w_row, fault_mask
 
     # ------------------------- phase B, batched ------------------------------
-    def phase_b_batched(st: SimState, cc: CostConfig, pc: PolicyConfig,
-                        va_row, w_row, sched_row, fault_mask):
-        """Conflict-aware vectorized fault engine.
+    def fault_requests(st: SimState, va_row, sched_row, fault_mask):
+        """The per-lane allocator requests of a faulting row.
 
         Host-precomputed first-thread-wins masks (``sched_row``) resolve
-        threads faulting the same PT entry or data page; ``alloc_many``
-        serializes the allocator counters through a tiny scan; everything
-        else — PT placement scatters, TLB fills, cycle/event accounting —
-        commits vectorized.  Bit-identical to ``phase_b_body`` run over
-        threads in index order (cycles to f32 rounding).
-
-        For a simulation resumed from a pre-populated state the host DO /
-        WINNER bits over-approximate (the schedule starts from an empty
-        address space) and are masked by phase A's actual miss set —
-        host-mapped is always a subset of device-mapped, so the masked
-        winner set is exactly the sequential fault set.  The per-PT-entry
-        first-winner masks are *not* taken from the host NEED bits here:
-        a resumed state can hold a truly-missing leaf whose host bit was
-        latched onto a masked-off winner (a cross-segment free can clear
-        a leaf while a sibling granule's data page stays mapped), so they
-        are recomputed from live state — a scatter-min of thread ids over
-        each (small) PT-level array, which is cheap next to the n_map
-        commits below and exact in every case.
+        threads faulting the same PT entry or data page.  For a simulation
+        resumed from a pre-populated state the host DO / WINNER bits
+        over-approximate (the schedule starts from an empty address space)
+        and are masked by phase A's actual miss set — host-mapped is always
+        a subset of device-mapped, so the masked winner set is exactly the
+        sequential fault set.  The per-PT-entry first-winner masks are
+        *not* taken from the host NEED bits here: a resumed state can hold
+        a truly-missing leaf whose host bit was latched onto a masked-off
+        winner (a cross-segment free can clear a leaf while a sibling
+        granule's data page stays mapped), so they are recomputed from
+        live state — a scatter-min of thread ids over each (small)
+        PT-level array, which is cheap next to the n_map commits and exact
+        in every case.
         """
         m = jnp.clip(jnp.where(va_row >= 0, va_row >> shift, 0), 0, n_map - 1)
         do = ((sched_row & SCHED_DO) > 0) & fault_mask
         winner = ((sched_row & SCHED_WINNER) > 0) & fault_mask
-        now = st.step
         tid = jnp.arange(T, dtype=I32)
 
         top_idx = jnp.clip(m >> (3 * rb), 0, st.top_node.shape[0] - 1)
@@ -772,11 +772,59 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
                     tid, mode="drop")
         else:
             slot_thread = None
-        nodes, slow, ok, act, gate, nfree, nrec, ptr, oom = \
-            alloc_mod.alloc_many(st.node_free, st.node_reclaimable,
-                                 st.interleave_ptr, st.oom_killed, wm,
-                                 pc.data_policy, pc.pt_policy, mc,
-                                 need_pt, winner, slot_thread=slot_thread)
+        return m, do, winner, pt_idx, need_pt, slot_thread
+
+    def phase_b_batched(st: SimState, cc: CostConfig, pc: PolicyConfig,
+                        va_row, w_row, sched_row, fault_mask, lane):
+        """Conflict-aware vectorized fault engine.
+
+        ``fault_requests`` gives each lane's allocator requests; the
+        allocator hands out their pages; everything else — PT placement
+        scatters, TLB fills, cycle/event accounting — commits vectorized
+        (``fault_commit``).  Bit-identical to ``phase_b_body`` run over
+        threads in index order (cycles to f32 rounding).
+
+        The allocator has two arms.  ``alloc.alloc_vector`` allocates the
+        row in one pass and says whether that is exact (no node's
+        allocator class changes inside the row); where it is not, in any
+        lane, the serialized ``alloc.alloc_many`` scan runs instead.
+        ``lane`` maps a per-lane function over the lane axis
+        (``jax.vmap``, or the identity in a solo run), so the arm choice —
+        the AND of every lane's bit — is one scalar ``lax.cond`` that
+        really skips the scan rather than a per-lane select running both.
+        Returns the state and the row's ``[batched, scan]`` arm count.
+        """
+        m, do, winner, pt_idx, need_pt, slot_thread = lane(fault_requests)(
+            st, va_row, sched_row, fault_mask)
+
+        def alloc_args(s, p):
+            return (s.node_free, s.node_reclaimable, s.interleave_ptr,
+                    s.oom_killed, wm, p.data_policy, p.pt_policy, mc)
+
+        with jax.named_scope("fault.alloc_batched"):
+            *batched, exact = lane(
+                lambda s, p, n_pt, n_d: alloc_mod.alloc_vector(
+                    *alloc_args(s, p), n_pt, n_d))(st, pc, need_pt, winner)
+            exact = jnp.all(exact)
+
+        def scan_arm():
+            with jax.named_scope("fault.alloc_scan"):
+                return lane(
+                    lambda s, p, n_pt, n_d, sl: alloc_mod.alloc_many(
+                        *alloc_args(s, p), n_pt, n_d, slot_thread=sl))(
+                            st, pc, need_pt, winner, slot_thread)
+
+        alloc = jax.lax.cond(exact, lambda: tuple(batched), scan_arm)
+        st = lane(fault_commit)(st, cc, w_row, m, do, winner, pt_idx, alloc)
+        return st, jnp.stack([exact, ~exact]).astype(I32)
+
+    def fault_commit(st: SimState, cc: CostConfig, w_row, m, do, winner,
+                     pt_idx, alloc):
+        """Commit one lane's allocations: PT placements, data pages, TLB
+        fills, cycles and counters."""
+        mid_idx, leaf_idx = pt_idx[2], pt_idx[3]
+        now = st.step
+        nodes, slow, ok, act, gate, nfree, nrec, ptr, oom = alloc
         fault = winner & gate          # threads that run the fault handler
         wait = do & ~winner & gate     # an earlier thread mapped m this step
         handled = wait | fault
@@ -908,61 +956,84 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
     # rides along as ordinary masked data.
     scan_op = _build_scan_op(mc, budget)
 
+    def timeline_row(st: SimState):
+        return (jnp.sum(st.cycles.total), jnp.sum(st.cycles.walk),
+                jnp.sum(st.cycles.stall), st.counters.faults,
+                st.node_free[0] + st.node_free[1],
+                jnp.sum((st.leaf_node >= 2).astype(I32)),
+                jnp.sum(((st.leaf_node >= 0) & (st.leaf_node < 2)).astype(I32)),
+                st.counters.walks, st.counters.data_migrations,
+                st.counters.l4_mig_success, st.cycles.migration,
+                jnp.sum(st.cycles.data_mem), jnp.sum(st.cycles.fault),
+                st.counters.l1_hits, st.counters.stlb_hits)
+
     # ``lean=True`` is the row body of a window the host schedule shows
     # has no free, no scan tick and a fault on every row (``plan_windows``,
     # WIN_LEAN): it drops the free and scan conds (``cond(False, f, id)``
     # is the identity) and runs phase B unconditionally (``cond(True, f,
     # id)`` is ``f``), so it is bit-identical to the general row and only
     # skips the carried-state copies in and out of each ``lax.cond``.
+    #
+    # ``lanes=True`` steps a lane-stacked state: every argument except the
+    # four schedule predicates carries a leading lane axis, and the step
+    # vmaps its per-lane parts itself so that the fault allocator's arm
+    # choice (``phase_b_batched``) stays outside the vmap.  The step
+    # returns the state, the timeline row and the row's allocator arm
+    # count ``[batched, scan]`` (zeros where phase B did not run).
     def step(st: SimState, cc: CostConfig, pc: PolicyConfig, x,
-             seg_of_map, seg_of_leaf, lean: bool = False):
+             seg_of_map, seg_of_leaf, lean: bool = False,
+             lanes: bool = False):
         va_row, w_row, fid, llc_rate, sched_row, do_free, do_scan, \
             has_fault, valid = x
-        if not lean:
-            with jax.named_scope("step.free"):
-                st = jax.lax.cond(
-                    do_free,
-                    lambda s: free_segment(s, fid, seg_of_map, seg_of_leaf),
-                    lambda s: s, st)
-            with jax.named_scope("step.scan"):
-                st = jax.lax.cond(
-                    do_scan, lambda s: scan_op(s, cc, pc, va_row, w_row),
-                    lambda s: s, st)
-        with jax.named_scope("step.access"):
-            st, fault_mask = phase_a(st, cc, va_row, w_row, llc_rate)
+        lane = jax.vmap if lanes else (lambda f: f)
+
+        def before_fault(st, cc, pc, va_row, w_row, fid, llc_rate,
+                         seg_of_map, seg_of_leaf):
+            if not lean:
+                with jax.named_scope("step.free"):
+                    st = jax.lax.cond(
+                        do_free,
+                        lambda s: free_segment(s, fid, seg_of_map,
+                                               seg_of_leaf),
+                        lambda s: s, st)
+                with jax.named_scope("step.scan"):
+                    st = jax.lax.cond(
+                        do_scan, lambda s: scan_op(s, cc, pc, va_row, w_row),
+                        lambda s: s, st)
+            with jax.named_scope("step.access"):
+                return phase_a(st, cc, va_row, w_row, llc_rate)
+
+        st, fault_mask = lane(before_fault)(st, cc, pc, va_row, w_row, fid,
+                                            llc_rate, seg_of_map,
+                                            seg_of_leaf)
 
         if phase_b == "batched":
             def run_phase_b(st):
                 return phase_b_batched(st, cc, pc, va_row, w_row, sched_row,
-                                       fault_mask)
+                                       fault_mask, lane)
         else:
-            def run_phase_b(st):
+            def sequential(st, cc, pc, va_row, w_row, fault_mask):
                 st2, _, _, _, _, _ = jax.lax.fori_loop(
                     0, T, phase_b_body, (st, cc, pc, va_row, w_row,
                                          fault_mask))
                 return st2
+
+            def run_phase_b(st):
+                return lane(sequential)(st, cc, pc, va_row, w_row,
+                                        fault_mask), no_alloc()
         # faults are bursty (populate) or rare (steady state): skip the
         # fault engine entirely on fault-free steps
         with jax.named_scope("step.fault"):
             if lean:
-                st = run_phase_b(st)
+                st, alloc_path = run_phase_b(st)
             else:
-                st = jax.lax.cond(has_fault, run_phase_b, lambda s: s, st)
+                st, alloc_path = jax.lax.cond(
+                    has_fault, run_phase_b, lambda s: (s, no_alloc()), st)
         # idle pad rows of a time-blocked window carry valid=False and
         # must not advance the step clock (it stamps TLB LRU and bern)
         st = dataclasses.replace(
             st, step=st.step + jnp.asarray(valid).astype(I32))
-
-        out = (jnp.sum(st.cycles.total), jnp.sum(st.cycles.walk),
-               jnp.sum(st.cycles.stall), st.counters.faults,
-               st.node_free[0] + st.node_free[1],
-               jnp.sum((st.leaf_node >= 2).astype(I32)),
-               jnp.sum(((st.leaf_node >= 0) & (st.leaf_node < 2)).astype(I32)),
-               st.counters.walks, st.counters.data_migrations,
-               st.counters.l4_mig_success, st.cycles.migration,
-               jnp.sum(st.cycles.data_mem), jnp.sum(st.cycles.fault),
-               st.counters.l1_hits, st.counters.stlb_hits)
-        return st, out
+        return st, lane(timeline_row)(st), alloc_path
 
     return step
 
@@ -1215,7 +1286,9 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
     Each kind runs under ``jax.named_scope("window.<kind>")``, and the
     per-step body's phases under ``step.free``, ``step.scan``,
     ``step.access`` and ``step.fault`` (``_build_step``; the lean body
-    has only the last two), and a hoisted scan tick under ``mig.scan``
+    has only the last two; inside ``step.fault`` the allocator's vector
+    pass is ``fault.alloc_batched`` and its scan arm
+    ``fault.alloc_scan``), and a hoisted scan tick under ``mig.scan``
     inside ``window.hoist`` (a replayed tick stays ``step.scan``), so
     device ops carry stable names in a profiler trace; scopes change op
     metadata only.
@@ -1248,21 +1321,6 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
             # keeps per-step semantics per lane
             return st2, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), outs)
 
-        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf,
-                      lean=False):
-            def per_step_row(s2, xr):
-                va_r, wr_r, fid_r, llc_r, sched_r, fr, sc, hf_r, vl_r = xr
-
-                def lane(st1, cc1, pc1, va1, w1, fid1, llc1, sched1,
-                         sm, sl):
-                    return step(st1, cc1, pc1,
-                                (va1, w1, fid1, llc1, sched1, fr, sc,
-                                 hf_r, vl_r), sm, sl, lean)
-                return jax.vmap(lane)(s2, cc, pc, va_r, wr_r, fid_r,
-                                      llc_r, sched_r, seg_of_map,
-                                      seg_of_leaf)
-            return jax.lax.scan(per_step_row, s, arrs)
-
         def run_scan(s, cc, pc, va_row, w_row):
             with jax.named_scope("mig.scan"):
                 return jax.vmap(scan_op)(s, cc, pc, va_row, w_row)
@@ -1270,15 +1328,13 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
         def run_fast(s, cc, va, wr, llc, vl):
             return fast_window(s, cc, va, wr, llc, vl)
 
-        def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf,
-                      lean=False):
-            def per_step_row(s2, xr):
-                return step(s2, cc, pc, xr, seg_of_map, seg_of_leaf, lean)
-            return jax.lax.scan(per_step_row, s, arrs)
-
         def run_scan(s, cc, pc, va_row, w_row):
             with jax.named_scope("mig.scan"):
                 return scan_op(s, cc, pc, va_row, w_row)
+
+    def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf, lean=False):
+        return scan_rows(functools.partial(step, lean=lean, lanes=lanes))(
+            s, cc, pc, arrs, seg_of_map, seg_of_leaf)
 
     def dsl(a, start, size):
         return jax.lax.dynamic_slice_in_dim(a, start, size, axis=0)
@@ -1303,14 +1359,14 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                 return branch(s)
         return run
 
-    def window(carry, xw, cc, pc, seg_of_map, seg_of_leaf):
+    def window(carry, cc, pc, xw, seg_of_map, seg_of_leaf):
         (va_w, wr_w, fid_w, llc_w, sched_w, vl_w, df_w, ds_w, hf_w,
          kind, a_idx, b_idx) = xw
 
         def fast_whole(s):
             s, o = run_fast(s, cc, va_w[:block], wr_w[:block],
                             llc_w[:block], vl_w[:block])
-            return s, pad_rows(o, block)
+            return s, pad_rows(o, block), no_alloc()
 
         branches = [scoped("window.fast", fast_whole)]
 
@@ -1320,9 +1376,9 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                     arrs = (va_w[:block], wr_w[:block], fid_w[:block],
                             llc_w[:block], sched_w[:block], df_w[:block],
                             ds_w[:block], hf_w[:block], vl_w[:block])
-                    s, o = run_steps(s, cc, pc, arrs, seg_of_map,
-                                     seg_of_leaf, lean)
-                    return s, pad_rows(o, block)
+                    s, o, n_alloc = run_steps(s, cc, pc, arrs, seg_of_map,
+                                              seg_of_leaf, lean)
+                    return s, pad_rows(o, block), n_alloc
                 return run
             branches.append(scoped("window.full", replay(False)))
             branches.append(scoped("window.lean", replay(True)))
@@ -1345,7 +1401,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                                     dsl(llc_w, b_idx, qh),
                                     dsl(vl_w, b_idx, qh))
                     chunks.append(o)
-                return s, pad_rows(cat_rows(chunks), ph + qh)
+                return s, pad_rows(cat_rows(chunks), ph + qh), no_alloc()
             branches.append(scoped("window.hoist", hoist_window))
 
         if split is not None:
@@ -1372,7 +1428,8 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                         dsl(df_w, a_idx, es), dsl(ds_w, a_idx, es),
                         dsl(hf_w, a_idx, es),
                         dsl(vl_w, a_idx, es) & span)
-                s, o = run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf)
+                s, o, n_alloc = run_steps(s, cc, pc, arrs, seg_of_map,
+                                          seg_of_leaf)
                 chunks.append(o)
                 if qs:
                     s, o = run_fast(s, cc, dsl(va_w, b_idx, qs),
@@ -1380,7 +1437,7 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
                                     dsl(llc_w, b_idx, qs),
                                     dsl(vl_w, b_idx, qs))
                     chunks.append(o)
-                return s, pad_rows(cat_rows(chunks), ps + es + qs)
+                return s, pad_rows(cat_rows(chunks), ps + es + qs), n_alloc
             branches.append(scoped("window.split", split_window))
 
         if len(branches) == 1:
@@ -1388,6 +1445,38 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
         return jax.lax.switch(kind, branches, carry)
 
     return window
+
+
+def no_alloc() -> jax.Array:
+    """A row or window that ran no fault allocator: ``[batched, scan]``."""
+    return jnp.zeros((2,), I32)
+
+
+def scan_rows(run_row):
+    """Scan ``run_row(state, cc, pc, x, seg_of_map, seg_of_leaf) ->
+    (state, outputs, alloc_path)`` over the rows (or windows) of ``xs``.
+
+    The runner returns ``(final state, stacked outputs, alloc_rows)``:
+    ``alloc_rows`` sums the allocator arm counts ``[batched, scan]`` of
+    every replayed faulting row, apart from the state and the timeline.
+    """
+    def run_all(st, cc, pc, xs, seg_of_map, seg_of_leaf):
+        def body(carry, x):
+            s, n = carry
+            s, out, path = run_row(s, cc, pc, x, seg_of_map, seg_of_leaf)
+            return (s, n + path), out
+        (final, n), outs = jax.lax.scan(body, (st, no_alloc()), xs)
+        return final, outs, n
+    return run_all
+
+
+def count_alloc_rows(tel, prefix: str, alloc_rows) -> None:
+    """Publish a runner's ``alloc_rows`` as ``<prefix>.alloc_rows{path=}``:
+    the replayed faulting rows whose allocator took the one-pass vector
+    arm (``batched``) or the serialized slot scan (``scan``)."""
+    batched, scan = (int(v) for v in np.asarray(alloc_rows))
+    tel.counter(f"{prefix}.alloc_rows", path="batched").inc(batched)
+    tel.counter(f"{prefix}.alloc_rows", path="scan").inc(scan)
 
 
 def _compiled_run(mc: MachineConfig, budget: int, phase_b: str = "batched",
@@ -1415,24 +1504,11 @@ def _compiled_run(mc: MachineConfig, budget: int, phase_b: str = "batched",
     key = (mc, budget, phase_b, engine, block, group, geom)
     if key not in _RUN_CACHE:
         if engine == "per_step":
-            step = _build_step(mc, budget, phase_b, group)
-
-            @jax.jit
-            def run_all(st, cc, pc, xs, seg_of_map, seg_of_leaf):
-                def body(s, x):
-                    return step(s, cc, pc, x, seg_of_map, seg_of_leaf)
-                return jax.lax.scan(body, st, xs)
+            run_rows = _build_step(mc, budget, phase_b, group)
         else:
-            window = _build_blocked_body(mc, budget, phase_b, group,
-                                         block, geom, lanes=False)
-
-            @jax.jit
-            def run_all(st, cc, pc, xs, seg_of_map, seg_of_leaf):
-                def body(s, xw):
-                    return window(s, xw, cc, pc, seg_of_map, seg_of_leaf)
-                return jax.lax.scan(body, st, xs)
-
-        _RUN_CACHE[key] = run_all
+            run_rows = _build_blocked_body(mc, budget, phase_b, group,
+                                           block, geom, lanes=False)
+        _RUN_CACHE[key] = jax.jit(scan_rows(run_rows))
     return _RUN_CACHE[key]
 
 
@@ -1721,8 +1797,9 @@ class TieredMemSimulator:
     oracle suites still exercise them).
 
     ``telemetry`` (optional :class:`repro.obs.Telemetry`) records run
-    counters, the fast/event window classification and a ``sim.run``
-    span.  All hooks are host-side: the compiled program and its outputs
+    counters, the fast/event window classification, the allocator arm
+    of each replayed faulting row (``sim.alloc_rows{path=batched|scan}``)
+    and a ``sim.run`` span.  All hooks are host-side: the compiled program and its outputs
     are bitwise-identical with telemetry on or off.
     """
 
@@ -1792,8 +1869,8 @@ class TieredMemSimulator:
                 tel.counter("sim.scan_ticks", arm="replay").inc(replayed)
             run_all = _compiled_run(mc, budget, self.phase_b, "blocked",
                                     block, group, plan.geom)
-            final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,
-                                  seg_of_leaf)
+            final, outs, alloc_rows = run_all(st0, self.cc, self.pc, xs,
+                                              seg_of_map, seg_of_leaf)
             timeline = {k: np.asarray(v)[plan.emit_valid]
                         for k, v in zip(TIMELINE_KEYS, outs)}
         else:
@@ -1801,7 +1878,9 @@ class TieredMemSimulator:
             xs = trace_xs(trace, mc, self.pc, start_step=start, sched=sched)
             run_all = _compiled_run(mc, budget, self.phase_b, "per_step",
                                     0, group)
-            final, outs = run_all(st0, self.cc, self.pc, xs, seg_of_map,
-                                  seg_of_leaf)
+            final, outs, alloc_rows = run_all(st0, self.cc, self.pc, xs,
+                                              seg_of_map, seg_of_leaf)
             timeline = {k: np.asarray(v) for k, v in zip(TIMELINE_KEYS, outs)}
+        if tel.enabled:
+            count_alloc_rows(tel, "sim", alloc_rows)
         return jax.device_get(final), timeline

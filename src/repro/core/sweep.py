@@ -63,6 +63,7 @@ Constraints inherited from the step being compiled once for all lanes:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -74,8 +75,9 @@ from ..obs import or_null
 from .config import CostConfig, MachineConfig, PolicyConfig
 from .sim import (DEFAULT_BLOCK, RunResult, SCHED_DO, TIMELINE_KEYS, Trace,
                   _build_blocked_body, _build_step, _normalize_blocked,
-                  fault_group_bound, fault_schedule, plan_windows, pow2ceil,
-                  scan_step_mask, seg_of_leaf_table, window_tiles)
+                  count_alloc_rows, fault_group_bound, fault_schedule,
+                  plan_windows, pow2ceil, scan_rows, scan_step_mask,
+                  seg_of_leaf_table, window_tiles)
 from .state import init_state
 
 I32 = jnp.int32
@@ -123,41 +125,18 @@ def _sweep_runner(mc: MachineConfig, budget: int, phase_b: str,
     key = (mc, budget, phase_b, engine, block, group, geom)
     if key not in _SWEEP_CACHE:
         if engine == "per_step":
-            step = _build_step(mc, budget, phase_b, group)
-
-            @jax.jit
-            def run_sweep(st, cc, pc, xs, seg_of_map, seg_of_leaf):
-                def body(carry, x):
-                    va_row, w_row, fid, llc, sched, do_free, do_scan, \
-                        has_fault, valid = x
-
-                    def lane(st1, cc1, pc1, va1, w1, fid1, llc1, sched1,
-                             sm, sl):
-                        # the schedule predicates stay un-batched so the
-                        # step's lax.conds keep skipping work under vmap;
-                        # the per-thread fault-schedule row is per-lane
-                        # (one per trace) and rides the vmap like the va
-                        # row
-                        return step(st1, cc1, pc1,
-                                    (va1, w1, fid1, llc1, sched1, do_free,
-                                     do_scan, has_fault, valid), sm, sl)
-                    return jax.vmap(lane)(carry, cc, pc, va_row, w_row,
-                                          fid, llc, sched, seg_of_map,
-                                          seg_of_leaf)
-                return jax.lax.scan(body, st, xs)
+            # the schedule predicates stay un-batched so the step's
+            # lax.conds keep skipping work; the step vmaps its per-lane
+            # parts itself (``lanes=True``)
+            run_rows = functools.partial(
+                _build_step(mc, budget, phase_b, group), lanes=True)
         else:
             # the window body (kind dispatch, fast/full/hoist/split
             # branches, lane vmaps) is shared with the solo runner —
             # sim._build_blocked_body, lanes=True
-            window = _build_blocked_body(mc, budget, phase_b, group,
-                                         block, geom, lanes=True)
-
-            @jax.jit
-            def run_sweep(st, cc, pc, xs, seg_of_map, seg_of_leaf):
-                def body(carry, xw):
-                    return window(carry, xw, cc, pc, seg_of_map,
-                                  seg_of_leaf)
-                return jax.lax.scan(body, st, xs)
+            run_rows = _build_blocked_body(mc, budget, phase_b, group,
+                                           block, geom, lanes=True)
+        run_sweep = jax.jit(scan_rows(run_rows))
 
         _SWEEP_CACHE[key] = run_sweep
     return _SWEEP_CACHE[key]
@@ -240,10 +219,13 @@ def sweep_lanes(mc: MachineConfig,
     among the full, ``sweep.windows_lean``), the rows the window scan
     covers (``sweep.rows``), the rows the per-step body replays
     (``sweep.replay_rows``) and the scan ticks by whether they run
-    hoisted or replayed (``sweep.scan_ticks{arm=hoist|replay}``).  On the
-    device the window kinds, step phases and hoisted scan ticks carry
-    ``jax.named_scope`` names (``window.*``, ``step.*``, ``mig.scan``;
-    see ``sim._build_blocked_body``).  Every hook is
+    hoisted or replayed (``sweep.scan_ticks{arm=hoist|replay}``), and
+    after the run the replayed faulting rows by the allocator arm they
+    took (``sweep.alloc_rows{path=batched|scan}``).  On the
+    device the window kinds, step phases, allocator arms and hoisted scan
+    ticks carry ``jax.named_scope`` names (``window.*``, ``step.*``,
+    ``fault.alloc_batched|scan``, ``mig.scan``; see
+    ``sim._build_blocked_body``).  Every hook is
     host-side Python: the compiled program and its outputs are
     bitwise-identical with telemetry on or off.
     """
@@ -414,11 +396,13 @@ def sweep_lanes(mc: MachineConfig,
                 tel.counter("sweep.replay_rows").inc(S)
 
     with tel.span("sweep.device", lanes=L, steps=S, engine=engine):
-        final, outs = jax.block_until_ready(
+        final, outs, alloc_rows = jax.block_until_ready(
             run_sweep(st0, lane_cc, lane_pc, xs, seg_of_map, seg_of_leaf))
     with tel.span("sweep.readback"):
         final = jax.device_get(final)
         outs = [np.asarray(o) for o in jax.device_get(outs)]
+        if tel.enabled:
+            count_alloc_rows(tel, "sweep", alloc_rows)
     if engine == "blocked":
         # [n_windows, R_out, L] -> [steps, L]: pad and capacity-slack
         # rows dropped in step order via the plan's emission mask

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import (CostConfig, MachineConfig, PolicyConfig,
-                        TieredMemSimulator, Trace, sweep,
+                        TieredMemSimulator, Trace, sweep, sweep_lanes,
                         FIRST_TOUCH, INTERLEAVE, PT_BIND_ALL, PT_BIND_HIGH,
                         PT_FOLLOW_DATA)
 from repro.core import alloc as alloc_mod
@@ -411,3 +411,82 @@ def test_lean_windows_bitwise(runner):
         assert (a.trace_name, a.policy_label) \
             == (b.trace_name, b.policy_label)
     assert got[0].summary()["faults"] > 0
+
+
+WATERMARK_POLICIES = [
+    PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
+                 autonuma=False),
+    PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_BIND_HIGH,
+                 autonuma=False),
+    PolicyConfig(data_policy=INTERLEAVE, pt_policy=PT_FOLLOW_DATA,
+                 autonuma=False),
+    PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_BIND_ALL,
+                 autonuma=False),
+]
+
+
+def watermark_trace(mc, delay, steps=160):
+    """Idle for ``delay`` rows, then every thread touches a fresh granule
+    each row: DRAM (100 pages a node, watermark 2) reaches its watermark
+    50-100 populate rows in, so later the larger the delay."""
+    T = mc.n_threads
+    va = np.full((steps, T), -1, np.int64)
+    rows = steps - delay
+    va[delay:] = np.arange(rows * T).reshape(rows, T)
+    return make_trace(mc, (va << mc.map_shift).astype(np.int32))
+
+
+@pytest.mark.parametrize("runner", ["solo", "lanes"])
+def test_allocator_arms_bitwise_across_watermarks(runner):
+    """Populate lanes whose DRAM nodes cross their watermark (and run dry)
+    inside lean windows, at a different row in each lane: the rows where
+    a node's allocator class changes take the allocator scan, the others
+    the one-pass vector arm.  The blocked engine equals the per-step
+    reference leaf for leaf and timeline bit for bit, solo and over 8
+    lanes; ``alloc_rows`` counts both arms, and the two counts sum to the
+    faulting replayed rows."""
+    mc = tiny_machine(dram_pages_per_node=100)
+    cc = CostConfig()
+    delays = [0, 5, 12, 17, 24, 29, 36, 41]
+    traces = [watermark_trace(mc, d) for d in delays]
+    pcs = [WATERMARK_POLICIES[i % 4] for i in range(len(delays))]
+    if runner == "solo":
+        picks = [0, 3, 6]
+        got, ref = [], []
+        for i in picks:
+            tel, tel_ref = Telemetry(), Telemetry()
+            got.append(TieredMemSimulator(mc=mc, cc=cc, pc=pcs[i], block=16,
+                                          telemetry=tel).run(traces[i]))
+            ref.append(TieredMemSimulator(
+                mc=mc, cc=cc, pc=pcs[i], engine="per_step", debug=True,
+                telemetry=tel_ref).run(traces[i]))
+            arms = [tel.metrics.value("sim.alloc_rows", path=p)
+                    for p in ("batched", "scan")]
+            assert arms == [tel_ref.metrics.value("sim.alloc_rows", path=p)
+                            for p in ("batched", "scan")]
+            assert min(arms) > 0, (i, arms)
+            assert sum(arms) == len(traces[i].va) - delays[i]
+        pcs, traces = [pcs[i] for i in picks], [traces[i] for i in picks]
+    else:
+        tel, tel_ref = Telemetry(), Telemetry()
+        got = sweep_lanes(mc, [cc] * len(pcs), pcs, traces, block=16,
+                          telemetry=tel)
+        ref = sweep_lanes(mc, [cc] * len(pcs), pcs, traces,
+                          engine="per_step", debug=True, telemetry=tel_ref)
+        arms = [tel.metrics.value("sweep.alloc_rows", path=p)
+                for p in ("batched", "scan")]
+        assert arms == [tel_ref.metrics.value("sweep.alloc_rows", path=p)
+                        for p in ("batched", "scan")]
+        assert min(arms) > 0, arms
+        # every row faults in some lane, so every window is lean
+        assert tel.metrics.value("sweep.windows_lean") == 10
+        assert sum(arms) == tel.metrics.value("sweep.replay_rows") == 160
+    for pc, a, b in zip(pcs, got, ref):
+        assert_states_bitwise(a.final_state, b.final_state, pc.label())
+        for k in a.timeline:
+            np.testing.assert_array_equal(a.timeline[k], b.timeline[k],
+                                          err_msg=f"{pc.label()}: tl/{k}")
+    # DRAM went below its watermark in every lane shown
+    wm = np.asarray(alloc_mod.watermark_pages(mc))
+    for a in got:
+        assert (np.asarray(a.final_state.node_free)[:2] <= wm[:2]).all()

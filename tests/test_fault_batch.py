@@ -10,11 +10,13 @@ page in one step), and through an OOM-during-burst.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (CostConfig, MachineConfig, PolicyConfig,
                         TieredMemSimulator, Trace, pad_trace, sweep,
                         FIRST_TOUCH, INTERLEAVE, PT_BIND_ALL, PT_BIND_HIGH,
                         PT_FOLLOW_DATA)
+from repro.core import alloc as alloc_mod
 from repro.core.ref import OracleSim
 from repro.core.sim import (SCHED_DO, SCHED_NEED_LEAF, SCHED_NEED_MID,
                             SCHED_NEED_ROOT, SCHED_NEED_TOP, SCHED_WINNER,
@@ -322,3 +324,95 @@ def test_resume_after_cross_segment_free_reallocates_leaf():
             np.asarray(getattr(finals["sequential"], arr)), err_msg=arr)
     # the real fault re-allocated the orphaned leaf
     assert int(np.asarray(finals["batched"].leaf_node)[0]) >= 0
+
+
+ALLOC_OUTPUTS = ("nodes", "slow", "ok", "act", "gate", "free", "rec", "ptr",
+                 "oom")
+POLICY_PAIRS = [(d, p) for d in (FIRST_TOUCH, INTERLEAVE)
+                for p in (PT_FOLLOW_DATA, PT_BIND_ALL, PT_BIND_HIGH)]
+
+
+def near_threshold_carry(rng, wm):
+    """Per-node free and reclaimable counts drawn at every class edge:
+    free at the watermark +- 0..8, free at 0..8, or far above; reclaim
+    at 0..8."""
+    n = wm.shape[0]
+    edge = rng.integers(0, 3, n)
+    free = np.where(edge == 0, wm + rng.integers(-8, 9, n),
+                    np.where(edge == 1, rng.integers(0, 9, n),
+                             wm + rng.integers(9, 400, n)))
+    rec = np.where(rng.random(n) < 0.7, rng.integers(0, 9, n), 0)
+    return np.maximum(free, 0), rec
+
+
+@pytest.mark.parametrize("machine", ["2tier", "3tier", "thp"])
+def test_alloc_vector_matches_alloc_many(machine):
+    """The one-pass row allocator against the serialized scan on random
+    carries near every class threshold, every data x PT policy pair and
+    OOM mid-row.  Where ``verified`` holds, every output equals the
+    full-depth scan bit for bit, and the slot-compacted scan on every
+    acting request; where it fails, the scan's end state really crosses a
+    node's class."""
+    T = 8
+    kw = {"3tier": dict(tier_pages_per_node=(600, 300, 2400)),
+          "thp": dict(page_order=9)}.get(machine, {})
+    mc = tiny_machine(n_threads=T, **kw)
+    wm = np.asarray(alloc_mod.watermark_pages(mc))
+    rng = np.random.default_rng(len(machine))
+
+    def run(fn, dpol, ppol, extra):
+        return jax.jit(lambda free, rec, ptr, oom, need_pt, need_d, *sl:
+                       fn(free, rec, ptr, oom, jnp.asarray(wm), dpol, ppol,
+                          mc, need_pt, need_d, **extra(*sl)))
+
+    seen = {"verified": 0, "crossing": 0, "oom_mid_row": 0,
+            "bhi_fallback": 0}
+    for dpol, ppol in POLICY_PAIRS:
+        full = run(alloc_mod.alloc_many, dpol, ppol, lambda: {})
+        slots = run(alloc_mod.alloc_many, dpol, ppol,
+                    lambda sl: {"slot_thread": sl})
+        vec = run(alloc_mod.alloc_vector, dpol, ppol, lambda: {})
+        for trial in range(40):
+            winners = rng.random(T) < rng.random()
+            need_pt = winners[:, None] & (rng.random((T, 4)) < 0.5)
+            need_d = winners & (rng.random(T) < 0.9)
+            free, rec = near_threshold_carry(rng, wm)
+            if ppol != PT_FOLLOW_DATA and trial % 4 == 0:
+                # DRAM exhausted: bind-all PT requests fail, BHi's fall
+                # back to the data policy
+                free[:2], rec[:2] = 0, 0
+            args = (jnp.asarray(free, jnp.int32), jnp.asarray(rec, jnp.int32),
+                    jnp.asarray(int(rng.integers(0, 50)), jnp.int32),
+                    jnp.asarray(bool(rng.random() < 0.05)),
+                    jnp.asarray(need_pt), jnp.asarray(need_d))
+            slot = np.cumsum(winners) - 1
+            slot_thread = np.full(T, T, np.int32)
+            slot_thread[slot[winners]] = np.where(winners)[0]
+
+            ref = [np.asarray(x) for x in full(*args)]
+            comp = [np.asarray(x) for x in slots(*args,
+                                                  jnp.asarray(slot_thread))]
+            *got, verified = [np.asarray(x) for x in vec(*args)]
+            where = f"{machine} d={dpol} pt={ppol} trial {trial}"
+            if not verified:
+                f1, r1 = ref[5], ref[6]
+                crossed = (((free > wm) != (f1 > wm)) | ((free > 0) != (f1 > 0))
+                           | ((rec > 0) != (r1 > 0)))
+                assert crossed.any(), where
+                seen["crossing"] += 1
+                continue
+            seen["verified"] += 1
+            gate = ref[4]
+            seen["oom_mid_row"] += int(gate[0] and not gate[-1])
+            seen["bhi_fallback"] += int(ppol == PT_BIND_HIGH
+                                        and (ref[3][:, :3] & ref[2][:, :3]
+                                             & (ref[0][:, :3] >= 2)).any())
+            act = ref[3]
+            for name, r, c, g in zip(ALLOC_OUTPUTS, ref, comp, got):
+                np.testing.assert_array_equal(g, r, err_msg=f"{where}: {name}")
+                if name in ("nodes", "slow", "ok"):
+                    c, g = np.where(act, c, 0), np.where(act, g, 0)
+                np.testing.assert_array_equal(g, c,
+                                              err_msg=f"{where}: {name} slots")
+    assert seen["verified"] > 100 and seen["crossing"] > 10, seen
+    assert seen["oom_mid_row"] > 0 and seen["bhi_fallback"] > 0, seen

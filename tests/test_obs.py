@@ -22,6 +22,7 @@ Three contracts:
 """
 import json
 import math
+import re
 
 import numpy as np
 import pytest
@@ -560,6 +561,69 @@ def test_sweep_runner_hlo_carries_window_and_step_scopes(monkeypatch, case):
     mig = [p for p in paths if "mig.scan" in p]
     assert bool(mig) == ("window.hoist" in want)
     assert all("window.hoist" in p and "step." not in p for p in mig)
+    # the fault allocator's arms, both under step.fault: the one-pass
+    # vector arm, then in every replaying window kind one conditional on
+    # a scalar (the AND over lanes, reduced outside the lane vmap) with
+    # the slot scan in one branch only: a per-lane predicate would have
+    # become a select running both arms
+    replay_kinds = want & {"window.full", "window.lean", "window.split"}
+    alloc = [p for p in paths if "fault.alloc_" in p]
+    assert {a for p in alloc for a in re.findall(r"fault\.alloc_[a-z]+", p)} \
+        == ({"fault.alloc_batched", "fault.alloc_scan"}
+            if replay_kinds else set())
+    assert all("step.fault/" in p for p in alloc)
+    comps = hlo_computations(text)
+    types = dict(re.findall(r"^\s*(%[\w.\-]+) = (\S+)", text, re.M))
+    conds = re.findall(r" conditional\((%[\w.\-]+),.*branch_computations="
+                       r"\{([^}]*)\}.*op_name=\"([^\"]*)\"", text)
+    for kind in replay_kinds:
+        mine = [(pred, branches.split(", "))
+                for pred, branches, path in conds if kind in path]
+        # the allocator's conditional: the scan in a branch, the vector
+        # pass outside it (the fault cond around both holds the two)
+        arm_conds = [
+            (pred, [hlo_reaches(comps, b, "fault.alloc_scan")
+                    for b in branches]) for pred, branches in mine
+            if not any(hlo_reaches(comps, b, "fault.alloc_batched")
+                       for b in branches)
+            and any(hlo_reaches(comps, b, "fault.alloc_scan")
+                    for b in branches)]
+        assert len(arm_conds) == 1, kind
+        (pred, hits), = arm_conds
+        assert types[pred] in ("pred[]", "s32[]"), (kind, types[pred])
+        assert hits.count(True) == 1, kind
+        if kind == "window.lean":
+            assert len(mine) == 1       # the lean body's only conditional
+
+
+def hlo_computations(text):
+    """Compiled HLO text -> {computation name: its lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def hlo_reaches(comps, root, needle):
+    """Does computation ``root``, or one it calls, hold an op whose
+    metadata names ``needle``?"""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if needle in line:
+                return True
+            todo += [c for c in re.findall(r"%[\w.\-]+", line)
+                     if c in comps]
+    return False
 
 
 @pytest.mark.parametrize("entry", ["sweep", "sim"])
