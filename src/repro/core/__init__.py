@@ -8,11 +8,12 @@ from .config import (CostConfig, MachineConfig, PolicyConfig, FIRST_TOUCH,
                      PT_BIND_ALL, PT_BIND_HIGH, PT_FOLLOW_DATA,
                      benchmark_machine, bhi, bhi_mig, bind_all, cxl_machine,
                      linux_default, nomad, tpp)
-from .sim import (RunResult, TieredMemSimulator, Trace, fault_schedule,
-                  fault_step_mask, pad_trace)
+from .sim import (RunResult, Trace, fault_schedule, fault_step_mask,
+                  pad_trace)
 from .state import SimState, init_state, is_dram, same_tier
 from .sweep import compile_count as sweep_compile_count
-from .sweep import lane_mesh, stack_policies, sweep, sweep_lanes
+from .sweep import (TieredMemSimulator, lane_mesh, stack_policies, sweep,
+                    sweep_lanes)
 from .workloads import TraceSpec, trace_digest
 from . import workloads
 

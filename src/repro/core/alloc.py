@@ -181,16 +181,17 @@ def alloc_many(node_free: jax.Array, node_reclaimable: jax.Array,
     compacts the serialized scan into *conflict groups*: a thread with no
     requests is the identity on the allocator carry and commutes with
     everything, so only the at-most-G allocating threads (the host
-    schedule's WINNER bits, ``sim.fault_group_bound``) need a scan slot —
-    each group is one allocating thread plus the silent threads behind
-    it.  The scan runs over the G slots in thread order and results
+    schedule's WINNER bits; the engine passes ``G = n_threads``) need a
+    scan slot — each group is one allocating thread plus the silent
+    threads behind it.  The scan runs over the G slots in thread order and results
     scatter back to the thread axis; per-thread OOM gates are
     reconstructed from the winners' failure prefix, which is exactly the
     thread-order latch (only allocating threads can trip it).  Requests
     from threads without a slot would be dropped — callers guarantee
     every requesting thread carries a WINNER bit (device winners are a
     subset of host winners).  ``None`` keeps the full ``n_threads``-deep
-    scan; both paths are bit-identical.
+    scan, the reference the tests hold the slot scan to; both are
+    bit-identical.
     """
     data_policy = jnp.asarray(data_policy)
     pt_policy = jnp.asarray(pt_policy)

@@ -55,10 +55,12 @@ hidden by out-of-order execution.
 
 Policy and cost knobs enter the compiled step as *traced pytree leaves*
 (``PolicyConfig``/``CostConfig`` are registered dataclasses): the step is
-policy-generic and vmap-able over a leading policy axis.  ``core.sweep``
-uses that to run N policies (and M same-shape traces) in ONE compiled
-``lax.scan``; the sequential path here shares the same compiled artifact
-across every policy of equal trace shape.  Step-schedule predicates that
+policy-generic and runs over a leading lane axis.  ``core.sweep`` builds
+one runner from it and uses it to run N policies (and M same-shape
+traces) in ONE compiled ``lax.scan``; a solo run is a one-lane sweep and
+shares the same compiled artifact across every policy of equal trace
+shape.  This module holds the program builders and the host window
+plan; it compiles nothing itself.  Step-schedule predicates that
 must stay un-batched for ``lax.cond`` to survive vmap — "a segment frees
 this step", "the AutoNUMA scan fires", "some thread faults" — are
 precomputed host-side from the trace, as is the per-(step, thread) fault
@@ -111,10 +113,9 @@ import numpy as np
 from . import alloc as alloc_mod
 from . import migrate as migrate_mod
 from . import tlbs
-from ..obs import or_null
 from .config import (CostConfig, MachineConfig, PolicyConfig, INTERLEAVE,
                      PT_BIND_HIGH, PT_FOLLOW_DATA)
-from .state import SimState, init_state, is_dram
+from .state import SimState, is_dram
 
 I32 = jnp.int32
 F32 = jnp.float32
@@ -332,24 +333,6 @@ def pow2ceil(n: int, floor: int = 1) -> int:
 DEFAULT_BLOCK = 64
 
 
-def fault_group_bound(sched: np.ndarray) -> int:
-    """Max winners (allocating threads) in any single step of a schedule.
-
-    This bounds the conflict-group count of ``alloc.alloc_many``'s
-    serialized allocator scan: every thread that touches the allocator in
-    a step carries the WINNER bit, and threads without requests commute
-    with everything, so the per-step scan depth collapses from
-    ``n_threads`` to this bound (each group = one allocating thread plus
-    the non-allocating threads behind it).  Device-side winners are a
-    subset of the host bits (resume masking), so the bound is safe for
-    resumed states too.
-    """
-    if sched.size == 0:
-        return 1
-    w = (sched & SCHED_WINNER) > 0
-    return max(int(w.sum(axis=1).max()), 1)
-
-
 @dataclasses.dataclass
 class RunResult:
     final_state: SimState          # host-side pytree of numpy arrays
@@ -412,8 +395,6 @@ class RunResult:
         }
 
 
-_RUN_CACHE: Dict[Tuple, object] = {}
-
 TIMELINE_KEYS = ("total_cycles", "walk_cycles", "stall_cycles", "faults",
                  "dram_free", "leaf_nvmm", "leaf_dram", "walks",
                  "data_migrations", "l4_mig_success", "migration_cycles",
@@ -450,26 +431,18 @@ def _build_scan_op(mc: MachineConfig, budget: int):
     return scan_op
 
 
-def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
-                group: Optional[int] = None):
-    """Build the policy-generic simulator step.
+def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched"):
+    """Build the policy-generic simulator step over a leading lane axis.
 
-    Only MachineConfig shapes, the AutoNUMA candidate bound ``budget``,
-    the ``phase_b`` engine choice and the allocator conflict-group bound
-    ``group`` are baked into the compile; every CostConfig/PolicyConfig
-    value arrives per call as a traced leaf of the ``cc``/``pc`` pytrees.
-    One compiled step therefore serves every policy bundle — and vmaps
-    over a leading policy axis for batched sweeps (``core.sweep``).
+    Only MachineConfig shapes, the AutoNUMA candidate bound ``budget``
+    and the ``phase_b`` engine choice are baked into the compile; every
+    CostConfig/PolicyConfig value arrives per call as a traced leaf of
+    the lane-stacked ``cc``/``pc`` pytrees, so one compiled step serves
+    every policy bundle (``core.sweep``; a solo run is one lane).
 
     ``phase_b="batched"`` (default) uses the conflict-aware vectorized
     fault engine; ``"sequential"`` keeps the historical per-thread
     ``fori_loop``, retained as the differential-testing reference.
-
-    ``group`` (``fault_group_bound``, power-of-two-quantized by callers
-    so compile keys stay stable) caps the number of allocating threads
-    per step and lets ``alloc.alloc_many`` compact its serialized
-    allocator scan from ``n_threads`` to that many conflict-group slots;
-    ``None`` keeps the full-depth scan.
     """
     assert phase_b in ("batched", "sequential"), phase_b
     T = mc.n_threads
@@ -760,22 +733,18 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
         # Conflict-group compaction of the allocator scan: only host
         # WINNER threads ever touch the allocator carry (everyone else is
         # the identity and commutes), so the serialized scan runs over
-        # ``group`` winner slots instead of all T threads.  Slot ids are
-        # the host schedule's winner prefix count; device-side winners
-        # (masked by phase A on resume) are a subset of the host bits, so
-        # every requesting thread owns a slot.
-        if group is not None:
-            host_w = (sched_row & SCHED_WINNER) > 0
-            slot = jnp.cumsum(host_w.astype(I32)) - 1
-            slot_thread = jnp.full((group,), T, I32).at[
-                jnp.where(host_w & (slot < group), slot, group)].set(
-                    tid, mode="drop")
-        else:
-            slot_thread = None
+        # winner slots, in thread order.  Slot ids are the host
+        # schedule's winner prefix count; device-side winners (masked by
+        # phase A on resume) are a subset of the host bits, so every
+        # requesting thread owns one of the T slots.
+        host_w = (sched_row & SCHED_WINNER) > 0
+        slot = jnp.cumsum(host_w.astype(I32)) - 1
+        slot_thread = jnp.full((T,), T, I32).at[
+            jnp.where(host_w & (slot < T), slot, T)].set(tid, mode="drop")
         return m, do, winner, pt_idx, need_pt, slot_thread
 
     def phase_b_batched(st: SimState, cc: CostConfig, pc: PolicyConfig,
-                        va_row, w_row, sched_row, fault_mask, lane):
+                        va_row, w_row, sched_row, fault_mask):
         """Conflict-aware vectorized fault engine.
 
         ``fault_requests`` gives each lane's allocator requests; the
@@ -787,14 +756,13 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
         The allocator has two arms.  ``alloc.alloc_vector`` allocates the
         row in one pass and says whether that is exact (no node's
         allocator class changes inside the row); where it is not, in any
-        lane, the serialized ``alloc.alloc_many`` scan runs instead.
-        ``lane`` maps a per-lane function over the lane axis
-        (``jax.vmap``, or the identity in a solo run), so the arm choice —
-        the AND of every lane's bit — is one scalar ``lax.cond`` that
-        really skips the scan rather than a per-lane select running both.
+        lane, the serialized ``alloc.alloc_many`` scan runs instead.  The
+        per-lane parts are vmapped here, so the arm choice — the AND of
+        every lane's bit — is one scalar ``lax.cond`` that really skips
+        the scan rather than a per-lane select running both.
         Returns the state and the row's ``[batched, scan]`` arm count.
         """
-        m, do, winner, pt_idx, need_pt, slot_thread = lane(fault_requests)(
+        m, do, winner, pt_idx, need_pt, slot_thread = jax.vmap(fault_requests)(
             st, va_row, sched_row, fault_mask)
 
         def alloc_args(s, p):
@@ -802,20 +770,21 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
                     s.oom_killed, wm, p.data_policy, p.pt_policy, mc)
 
         with jax.named_scope("fault.alloc_batched"):
-            *batched, exact = lane(
+            *batched, exact = jax.vmap(
                 lambda s, p, n_pt, n_d: alloc_mod.alloc_vector(
                     *alloc_args(s, p), n_pt, n_d))(st, pc, need_pt, winner)
             exact = jnp.all(exact)
 
         def scan_arm():
             with jax.named_scope("fault.alloc_scan"):
-                return lane(
+                return jax.vmap(
                     lambda s, p, n_pt, n_d, sl: alloc_mod.alloc_many(
                         *alloc_args(s, p), n_pt, n_d, slot_thread=sl))(
                             st, pc, need_pt, winner, slot_thread)
 
         alloc = jax.lax.cond(exact, lambda: tuple(batched), scan_arm)
-        st = lane(fault_commit)(st, cc, w_row, m, do, winner, pt_idx, alloc)
+        st = jax.vmap(fault_commit)(st, cc, w_row, m, do, winner, pt_idx,
+                                    alloc)
         return st, jnp.stack([exact, ~exact]).astype(I32)
 
     def fault_commit(st: SimState, cc: CostConfig, w_row, m, do, winner,
@@ -974,18 +943,16 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
     # id)`` is ``f``), so it is bit-identical to the general row and only
     # skips the carried-state copies in and out of each ``lax.cond``.
     #
-    # ``lanes=True`` steps a lane-stacked state: every argument except the
-    # four schedule predicates carries a leading lane axis, and the step
-    # vmaps its per-lane parts itself so that the fault allocator's arm
-    # choice (``phase_b_batched``) stays outside the vmap.  The step
-    # returns the state, the timeline row and the row's allocator arm
-    # count ``[batched, scan]`` (zeros where phase B did not run).
+    # The step runs a lane-stacked state: every argument except the four
+    # schedule predicates carries a leading lane axis, and the step vmaps
+    # its per-lane parts itself so that the fault allocator's arm choice
+    # (``phase_b_batched``) stays outside the vmap.  The step returns the
+    # state, the timeline row and the row's allocator arm count
+    # ``[batched, scan]`` (zeros where phase B did not run).
     def step(st: SimState, cc: CostConfig, pc: PolicyConfig, x,
-             seg_of_map, seg_of_leaf, lean: bool = False,
-             lanes: bool = False):
+             seg_of_map, seg_of_leaf, lean: bool = False):
         va_row, w_row, fid, llc_rate, sched_row, do_free, do_scan, \
             has_fault, valid = x
-        lane = jax.vmap if lanes else (lambda f: f)
 
         def before_fault(st, cc, pc, va_row, w_row, fid, llc_rate,
                          seg_of_map, seg_of_leaf):
@@ -1003,14 +970,14 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
             with jax.named_scope("step.access"):
                 return phase_a(st, cc, va_row, w_row, llc_rate)
 
-        st, fault_mask = lane(before_fault)(st, cc, pc, va_row, w_row, fid,
-                                            llc_rate, seg_of_map,
-                                            seg_of_leaf)
+        st, fault_mask = jax.vmap(before_fault)(st, cc, pc, va_row, w_row,
+                                                fid, llc_rate, seg_of_map,
+                                                seg_of_leaf)
 
         if phase_b == "batched":
             def run_phase_b(st):
                 return phase_b_batched(st, cc, pc, va_row, w_row, sched_row,
-                                       fault_mask, lane)
+                                       fault_mask)
         else:
             def sequential(st, cc, pc, va_row, w_row, fault_mask):
                 st2, _, _, _, _, _ = jax.lax.fori_loop(
@@ -1019,8 +986,8 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
                 return st2
 
             def run_phase_b(st):
-                return lane(sequential)(st, cc, pc, va_row, w_row,
-                                        fault_mask), no_alloc()
+                return jax.vmap(sequential)(st, cc, pc, va_row, w_row,
+                                            fault_mask), no_alloc()
         # faults are bursty (populate) or rare (steady state): skip the
         # fault engine entirely on fault-free steps
         with jax.named_scope("step.fault"):
@@ -1033,7 +1000,7 @@ def _build_step(mc: MachineConfig, budget: int, phase_b: str = "batched",
         # must not advance the step clock (it stamps TLB LRU and bern)
         st = dataclasses.replace(
             st, step=st.step + jnp.asarray(valid).astype(I32))
-        return st, lane(timeline_row)(st), alloc_path
+        return st, jax.vmap(timeline_row)(st), alloc_path
 
     return step
 
@@ -1240,29 +1207,26 @@ def _geom_rows_in(geom, block: int) -> int:
     return block
 
 
-def _normalize_blocked(budget: int, phase_b: str, group: Optional[int],
-                       geom):
+def _normalize_blocked(budget: int, phase_b: str, geom):
     """Canonicalize compile-key components a blocked geometry provably
     never feeds into the compiled program, so distinct callers share one
     executable: without a full/split branch no per-step body is built
-    (the phase-B engine choice and allocator group bound are dead), and
-    without any scan-capable branch the AutoNUMA candidate bound is dead
-    too."""
+    (the phase-B engine choice is dead), and without any scan-capable
+    branch the AutoNUMA candidate bound is dead too."""
     needs_step = geom is not None and (bool(geom[0]) or geom[2] is not None)
     needs_scan = needs_step or (geom is not None and geom[1] is not None)
     if not needs_step:
-        phase_b, group = "batched", None
+        phase_b = "batched"
     if not needs_scan:
         budget = 0
-    return budget, phase_b, group
+    return budget, phase_b
 
 
 def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
-                        group: Optional[int], block: int, geom,
-                        lanes: bool):
-    """Build the per-window body of the time-blocked engine, shared by
-    the solo runner (``_compiled_run``) and the lane sweep
-    (``sweep._sweep_runner``, ``lanes=True``).
+                        block: int, geom):
+    """Build the per-window body of the time-blocked engine over a
+    leading lane axis (the runner is ``sweep._sweep_runner``; a solo run
+    is one lane).
 
     ``geom`` is the host-quantized split geometry from
     :func:`plan_windows` — ``None`` (every window is fast: the compiled
@@ -1307,33 +1271,25 @@ def _build_blocked_body(mc: MachineConfig, budget: int, phase_b: str,
     hoist = geom[1] if geom is not None else None
     split = geom[2] if geom is not None else None
     needs_step = has_full or split is not None
-    step = _build_step(mc, budget, phase_b, group) if needs_step else None
+    step = _build_step(mc, budget, phase_b) if needs_step else None
     scan_op = _build_scan_op(mc, budget) if hoist is not None else None
     r_out = _geom_out_rows(geom, block)
 
-    if lanes:
-        def run_fast(s, cc, va, wr, llc, vl):
-            def lane(st1, cc1, va1, w1, llc1):
-                return fast_window(st1, cc1, va1, w1, llc1, vl)
-            st2, outs = jax.vmap(lane, in_axes=(0, 0, 1, 1, 1))(
-                s, cc, va, wr, llc)
-            # back to rows-major [rows, L] so the flattened timeline
-            # keeps per-step semantics per lane
-            return st2, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), outs)
+    def run_fast(s, cc, va, wr, llc, vl):
+        def lane(st1, cc1, va1, w1, llc1):
+            return fast_window(st1, cc1, va1, w1, llc1, vl)
+        st2, outs = jax.vmap(lane, in_axes=(0, 0, 1, 1, 1))(
+            s, cc, va, wr, llc)
+        # back to rows-major [rows, L] so the flattened timeline keeps
+        # per-step semantics per lane
+        return st2, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), outs)
 
-        def run_scan(s, cc, pc, va_row, w_row):
-            with jax.named_scope("mig.scan"):
-                return jax.vmap(scan_op)(s, cc, pc, va_row, w_row)
-    else:
-        def run_fast(s, cc, va, wr, llc, vl):
-            return fast_window(s, cc, va, wr, llc, vl)
-
-        def run_scan(s, cc, pc, va_row, w_row):
-            with jax.named_scope("mig.scan"):
-                return scan_op(s, cc, pc, va_row, w_row)
+    def run_scan(s, cc, pc, va_row, w_row):
+        with jax.named_scope("mig.scan"):
+            return jax.vmap(scan_op)(s, cc, pc, va_row, w_row)
 
     def run_steps(s, cc, pc, arrs, seg_of_map, seg_of_leaf, lean=False):
-        return scan_rows(functools.partial(step, lean=lean, lanes=lanes))(
+        return scan_rows(functools.partial(step, lean=lean))(
             s, cc, pc, arrs, seg_of_map, seg_of_leaf)
 
     def dsl(a, start, size):
@@ -1470,48 +1426,6 @@ def scan_rows(run_row):
     return run_all
 
 
-def count_alloc_rows(tel, prefix: str, alloc_rows) -> None:
-    """Publish a runner's ``alloc_rows`` as ``<prefix>.alloc_rows{path=}``:
-    the replayed faulting rows whose allocator took the one-pass vector
-    arm (``batched``) or the serialized slot scan (``scan``)."""
-    batched, scan = (int(v) for v in np.asarray(alloc_rows))
-    tel.counter(f"{prefix}.alloc_rows", path="batched").inc(batched)
-    tel.counter(f"{prefix}.alloc_rows", path="scan").inc(scan)
-
-
-def _compiled_run(mc: MachineConfig, budget: int, phase_b: str = "batched",
-                  engine: str = "blocked", block: int = DEFAULT_BLOCK,
-                  group: Optional[int] = None, geom=None):
-    """One jitted runner per (machine shape, AutoNUMA bound, phase-B
-    engine, execution engine, window size, allocator group bound, split
-    geometry).
-
-    Policy and cost configs are traced arguments, so every policy bundle —
-    and every CostConfig variation — reuses the same compiled artifact for
-    a given trace shape.  ``engine="blocked"`` scans window tiles through
-    the kind-dispatched body of :func:`_build_blocked_body` (``geom`` is
-    the quantized split geometry from :func:`plan_windows`, part of the
-    compile key); ``"per_step"`` is the retained step-at-a-time
-    reference.  Blocked keys are normalized first: parameters a geometry
-    never compiles (phase-B engine / group without a per-step branch,
-    budget without any scan) collapse to canonical values so those
-    programs keep quantizing across trace mixes.
-    """
-    assert engine in ("blocked", "per_step"), engine
-    if engine == "blocked":
-        budget, phase_b, group = _normalize_blocked(budget, phase_b, group,
-                                                    geom)
-    key = (mc, budget, phase_b, engine, block, group, geom)
-    if key not in _RUN_CACHE:
-        if engine == "per_step":
-            run_rows = _build_step(mc, budget, phase_b, group)
-        else:
-            run_rows = _build_blocked_body(mc, budget, phase_b, group,
-                                           block, geom, lanes=False)
-        _RUN_CACHE[key] = jax.jit(scan_rows(run_rows))
-    return _RUN_CACHE[key]
-
-
 def seg_of_leaf_table(trace: Trace, mc: MachineConfig) -> jax.Array:
     seg_of_map = jnp.asarray(trace.seg_of_map, I32)
     n_leaf = mc.n_leaf_pages
@@ -1520,27 +1434,10 @@ def seg_of_leaf_table(trace: Trace, mc: MachineConfig) -> jax.Array:
     return seg_of_map[jnp.asarray(leaf_first, I32)]
 
 
-def trace_xs(trace: Trace, mc: MachineConfig, pc: PolicyConfig,
-             start_step: int = 0, sched: Optional[np.ndarray] = None):
-    """Per-step scan inputs for one trace: rows + schedule predicates."""
-    do_free = np.asarray(trace.free_seg) >= 0
-    do_scan = scan_step_mask(trace.n_steps, int(pc.autonuma_period),
-                             enabled=bool(pc.autonuma), start_step=start_step)
-    if sched is None:
-        sched = fault_schedule(trace, mc)
-    return (jnp.asarray(trace.va, I32), jnp.asarray(trace.is_write),
-            jnp.asarray(trace.free_seg, I32), jnp.asarray(trace.llc, F32),
-            jnp.asarray(sched), jnp.asarray(do_free), jnp.asarray(do_scan),
-            jnp.asarray((sched & SCHED_DO).any(axis=1)),
-            jnp.ones((trace.n_steps,), jnp.bool_))
-
-
 # Idle-pad fill values for the nine per-step window arrays, in xs order:
 # (va, is_write, free_seg, llc, sched, valid, do_free, do_scan,
 # has_fault).  Load-bearing: sched=0 carries no DO/WINNER bits, fid=-1
-# frees nothing, valid=False gates the step clock — shared by the solo
-# (blocked_xs) and sweep (sweep_lanes) tilings so pad-row semantics can
-# never diverge between them.
+# frees nothing, valid=False gates the step clock.
 WINDOW_PAD_FILLS = (-1, False, -1, 0.0, 0, False, False, False, False)
 
 
@@ -1741,146 +1638,3 @@ def plan_windows(do_free, do_scan, has_fault, n_steps: int,
         counts=tuple(int((sem == k).sum()) for k in range(4)),
         n_lean=int((kinds == WIN_LEAN).sum()),
         scan_ticks=(len(hoist_rows), int(ds[kinds != WIN_HOIST].sum())))
-
-
-def blocked_xs(trace: Trace, mc: MachineConfig, pc: PolicyConfig,
-               start_step: int = 0, block: int = DEFAULT_BLOCK,
-               sched: Optional[np.ndarray] = None):
-    """Window-tiled scan inputs for the time-blocked engine.
-
-    Returns ``(xs, plan)``: ``xs`` carries every per-step row (windows
-    row-padded to ``plan.rows_in``) plus the plan's per-window branch
-    index and segment offsets; ``plan`` is the :class:`WindowPlan`
-    whose ``emit_valid`` maps the scan's ``[n_windows, R_out]`` outputs
-    back to trace steps (idle pad and capacity-slack rows are dropped
-    when the per-step timeline is reassembled).
-    """
-    S = trace.n_steps
-    if sched is None:
-        sched = fault_schedule(trace, mc)
-    do_free = np.asarray(trace.free_seg) >= 0
-    do_scan = scan_step_mask(S, int(pc.autonuma_period),
-                             enabled=bool(pc.autonuma),
-                             start_step=start_step)
-    has_fault = np.asarray((sched & SCHED_DO) > 0).any(axis=1)
-    plan = plan_windows(do_free, do_scan, has_fault, S, block)
-    va, wr, fid, llc, sch, vl, df, ds, hf = window_tiles(
-        (trace.va.astype(np.int32), np.asarray(trace.is_write, bool),
-         np.asarray(trace.free_seg, np.int32),
-         np.asarray(trace.llc, np.float32), sched, np.ones((S,), bool),
-         do_free, do_scan, has_fault),
-        S, block, rows_to=plan.rows_in)
-    xs = (jnp.asarray(va), jnp.asarray(wr), jnp.asarray(fid),
-          jnp.asarray(llc), jnp.asarray(sch), jnp.asarray(vl),
-          jnp.asarray(df), jnp.asarray(ds), jnp.asarray(hf),
-          jnp.asarray(plan.kind), jnp.asarray(plan.seg_a),
-          jnp.asarray(plan.seg_b))
-    return xs, plan
-
-
-class TieredMemSimulator:
-    """Public facade: configure once, run traces under a policy bundle.
-
-    ``phase_b`` selects the fault engine: ``"batched"`` (default, the
-    conflict-aware vectorized path) or ``"sequential"`` (the per-thread
-    ``fori_loop`` reference the batched engine is tested against).
-
-    ``engine`` selects the stepper: ``"blocked"`` (default — the
-    time-blocked fast path over ``block``-step windows, bit-identical to
-    per-step execution) or ``"per_step"`` (the retained one-step-per-scan
-    reference).
-
-    The reference paths (``engine="per_step"`` / ``phase_b="sequential"``)
-    are differential-testing oracles, not production engines: after two
-    PRs of soak they are gated behind ``debug=True`` so production code
-    cannot silently run the slow paths (``tests/test_blocked.py`` and the
-    oracle suites still exercise them).
-
-    ``telemetry`` (optional :class:`repro.obs.Telemetry`) records run
-    counters, the fast/event window classification, the allocator arm
-    of each replayed faulting row (``sim.alloc_rows{path=batched|scan}``)
-    and a ``sim.run`` span.  All hooks are host-side: the compiled program and its outputs
-    are bitwise-identical with telemetry on or off.
-    """
-
-    def __init__(self, mc: MachineConfig = MachineConfig(),
-                 cc: CostConfig = CostConfig(),
-                 pc: PolicyConfig = PolicyConfig(),
-                 phase_b: str = "batched",
-                 engine: str = "blocked",
-                 block: int = DEFAULT_BLOCK,
-                 debug: bool = False,
-                 telemetry=None):
-        assert engine in ("blocked", "per_step"), engine
-        if (engine != "blocked" or phase_b != "batched") and not debug:
-            raise ValueError(
-                f"engine={engine!r} phase_b={phase_b!r} are reference "
-                f"(oracle) paths; pass debug=True to run them")
-        self.mc, self.cc, self.pc = mc, cc, pc
-        self.phase_b = phase_b
-        self.engine = engine
-        self.block = int(block)
-        self.debug = bool(debug)
-        self.telemetry = or_null(telemetry)
-
-    def run(self, trace: Trace, state: Optional[SimState] = None) -> RunResult:
-        tel = self.telemetry
-        with tel.span("sim.run", steps=trace.n_steps, engine=self.engine,
-                      trace=trace.name):
-            final, timeline = self._run(trace, state)
-        tel.counter("sim.runs", engine=self.engine).inc()
-        return RunResult(final_state=final, timeline=timeline,
-                         trace_name=trace.name, policy_label=self.pc.label())
-
-    def _run(self, trace: Trace, state: Optional[SimState]):
-        tel = self.telemetry
-        mc = self.mc
-        assert trace.va.shape[1] == mc.n_threads, \
-            f"trace has {trace.va.shape[1]} threads, machine {mc.n_threads}"
-        budget = min(int(self.pc.autonuma_budget), mc.n_map)
-        sched = fault_schedule(trace, mc)      # memoized; computed once
-        group = None
-        if self.phase_b == "batched":
-            group = min(pow2ceil(fault_group_bound(sched)), mc.n_threads)
-
-        seg_of_map = jnp.asarray(trace.seg_of_map, I32)
-        seg_of_leaf = seg_of_leaf_table(trace, mc)
-
-        st0 = state if state is not None else init_state(mc)
-        start = int(np.asarray(state.step)) if state is not None else 0
-
-        if self.engine == "blocked":
-            block = min(self.block, pow2ceil(trace.n_steps))
-            xs, plan = blocked_xs(trace, mc, self.pc, start_step=start,
-                                  block=block, sched=sched)
-            if tel.enabled:
-                # the host-side window classification is exactly the
-                # fast/full/hoist/split dispatch the blocked engine ran,
-                # and the lean share of the full windows
-                n_fast, _, n_hoist, n_split = plan.counts
-                tel.counter("sim.windows_event").inc(
-                    plan.n_windows - n_fast)
-                tel.counter("sim.windows_fast").inc(n_fast)
-                tel.counter("sim.windows_hoist").inc(n_hoist)
-                tel.counter("sim.windows_split").inc(n_split)
-                tel.counter("sim.windows_lean").inc(plan.n_lean)
-                hoisted, replayed = plan.scan_ticks
-                tel.counter("sim.scan_ticks", arm="hoist").inc(hoisted)
-                tel.counter("sim.scan_ticks", arm="replay").inc(replayed)
-            run_all = _compiled_run(mc, budget, self.phase_b, "blocked",
-                                    block, group, plan.geom)
-            final, outs, alloc_rows = run_all(st0, self.cc, self.pc, xs,
-                                              seg_of_map, seg_of_leaf)
-            timeline = {k: np.asarray(v)[plan.emit_valid]
-                        for k, v in zip(TIMELINE_KEYS, outs)}
-        else:
-            tel.counter("sim.steps").inc(trace.n_steps)
-            xs = trace_xs(trace, mc, self.pc, start_step=start, sched=sched)
-            run_all = _compiled_run(mc, budget, self.phase_b, "per_step",
-                                    0, group)
-            final, outs, alloc_rows = run_all(st0, self.cc, self.pc, xs,
-                                              seg_of_map, seg_of_leaf)
-            timeline = {k: np.asarray(v) for k, v in zip(TIMELINE_KEYS, outs)}
-        if tel.enabled:
-            count_alloc_rows(tel, "sim", alloc_rows)
-        return jax.device_get(final), timeline

@@ -9,13 +9,16 @@ simulator step (``sim._build_step``) over it, and runs the whole grid as a
 single compiled ``lax.scan`` — one compile per trace shape, one device
 program per figure.
 
-Two entry points share the engine:
+:func:`sweep_lanes` is the one engine entry point — one lane per
+independent ``(cost, policy, trace)`` tuple, the microbatch primitive of
+the simulation service (``repro.service``): a broker bucketing arbitrary
+concurrent queries by trace shape flushes each bucket through one call
+here.  It does the host prepare, holds the one compile cache, dispatches,
+reads back, and counts and times.  Two facades call it:
 
-  * :func:`sweep` — the figure-style cross product: N policies × M traces.
-  * :func:`sweep_lanes` — one lane per independent ``(cost, policy,
-    trace)`` tuple.  This is the microbatch primitive of the simulation
-    service (``repro.service``): a broker bucketing arbitrary concurrent
-    queries by trace shape flushes each bucket through one call here.
+  * :func:`sweep` — the figure-style cross product: N policies × M traces;
+  * :class:`TieredMemSimulator` — one trace under one policy bundle, run
+    as a one-lane sweep.
 
 Execution is time-blocked by default (``engine="blocked"``, see
 ``core.sim``): the scan iterates fixed ``[block, T]`` step-windows,
@@ -41,10 +44,10 @@ body.  On a single-device host the mesh degenerates and results are
 bit-identical to the unsharded path.
 
 Correctness contract: a sweep lane is bit-identical (placements,
-counters; cycles to float32 rounding — and bit-exact between the blocked
-and per-step engines) to the corresponding sequential
-``TieredMemSimulator`` run and to the pure-Python ``core.ref`` oracle —
-``tests/test_sweep.py``, ``tests/test_blocked.py`` and
+counters, cycles and timeline — and bit-exact between the blocked and
+per-step engines) to the corresponding one-lane ``TieredMemSimulator``
+run, and matches the pure-Python ``core.ref`` oracle (cycles to float32
+rounding) — ``tests/test_sweep.py``, ``tests/test_blocked.py`` and
 ``tests/test_service.py`` enforce these.
 
 Constraints inherited from the step being compiled once for all lanes:
@@ -57,13 +60,12 @@ Constraints inherited from the step being compiled once for all lanes:
     swept policies (or the explicit ``budget`` override, which may only
     raise it); per-lane budgets gate through traced masks, so an
     over-provisioned bound never changes results — brokers quantize it to
-    keep compile keys stable across bursts.  The allocator conflict-group
-    bound (``group``) quantizes the same way: power-of-two of the batch
-    maximum, overridable upward.
+    keep compile keys stable across bursts;
+  * lanes resumed from ``states`` must share one step clock (the scan
+    schedule again).
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -75,17 +77,16 @@ from ..obs import or_null
 from .config import CostConfig, MachineConfig, PolicyConfig
 from .sim import (DEFAULT_BLOCK, RunResult, SCHED_DO, TIMELINE_KEYS, Trace,
                   _build_blocked_body, _build_step, _normalize_blocked,
-                  count_alloc_rows, fault_group_bound, fault_schedule,
-                  plan_windows, pow2ceil, scan_rows, scan_step_mask,
-                  seg_of_leaf_table, window_tiles)
-from .state import init_state
+                  fault_schedule, plan_windows, pow2ceil, scan_rows,
+                  scan_step_mask, seg_of_leaf_table, window_tiles)
+from .state import SimState, init_state
 
 I32 = jnp.int32
 F32 = jnp.float32
 
-# One jitted vmapped scan per (machine, budget, engines, block, group,
-# split geometry); jax's jit cache then holds one executable per (lane
-# count, trace shape, lane sharding).
+# One jitted vmapped scan per (machine, budget, engines, block, split
+# geometry); jax's jit cache then holds one executable per (lane count,
+# trace shape, lane sharding).
 _SWEEP_CACHE: Dict[Tuple, object] = {}
 
 
@@ -117,29 +118,52 @@ def _stack_leaves(objs):
 
 
 def _sweep_runner(mc: MachineConfig, budget: int, phase_b: str,
-                  engine: str, block: int, group: Optional[int],
-                  geom=None):
+                  engine: str, block: int, geom=None):
     if engine == "blocked":
-        budget, phase_b, group = _normalize_blocked(budget, phase_b, group,
-                                                    geom)
-    key = (mc, budget, phase_b, engine, block, group, geom)
+        budget, phase_b = _normalize_blocked(budget, phase_b, geom)
+    key = (mc, budget, phase_b, engine, block, geom)
     if key not in _SWEEP_CACHE:
         if engine == "per_step":
             # the schedule predicates stay un-batched so the step's
             # lax.conds keep skipping work; the step vmaps its per-lane
-            # parts itself (``lanes=True``)
-            run_rows = functools.partial(
-                _build_step(mc, budget, phase_b, group), lanes=True)
+            # parts itself
+            run_rows = _build_step(mc, budget, phase_b)
         else:
-            # the window body (kind dispatch, fast/full/hoist/split
-            # branches, lane vmaps) is shared with the solo runner —
-            # sim._build_blocked_body, lanes=True
-            run_rows = _build_blocked_body(mc, budget, phase_b, group,
-                                           block, geom, lanes=True)
-        run_sweep = jax.jit(scan_rows(run_rows))
-
-        _SWEEP_CACHE[key] = run_sweep
+            # the window body: kind dispatch, fast/full/hoist/split
+            # branches, lane vmaps
+            run_rows = _build_blocked_body(mc, budget, phase_b, block, geom)
+        _SWEEP_CACHE[key] = jax.jit(scan_rows(run_rows))
     return _SWEEP_CACHE[key]
+
+
+def check_engine(engine: str, phase_b: str, debug: bool) -> None:
+    """The reference paths (``engine="per_step"``, ``phase_b=
+    "sequential"``) are differential-testing oracles: they run only with
+    ``debug=True``."""
+    if engine not in ("blocked", "per_step"):
+        raise ValueError(f"unknown engine {engine!r}")
+    if (engine != "blocked" or phase_b != "batched") and not debug:
+        raise ValueError(
+            f"engine={engine!r} phase_b={phase_b!r} are reference (oracle) "
+            "paths; pass debug=True to run them")
+
+
+def event_masks(mc: MachineConfig, traces: Sequence[Trace], period: int,
+                autonuma: bool, start_step: int = 0):
+    """The host schedule of a call: each trace's fault schedule and the
+    lane-union step predicates ``(do_free, do_scan, has_fault)`` that
+    :func:`sim.plan_windows` classifies windows from.  ``start_step`` is
+    the step clock the lanes resume from (the scan ticks' phase)."""
+    S = traces[0].va.shape[0]
+    scheds = [fault_schedule(tr, mc) for tr in traces]
+    do_free = np.zeros((S,), bool)
+    has_fault = np.zeros((S,), bool)
+    for sc, tr in zip(scheds, traces):
+        do_free |= np.asarray(tr.free_seg) >= 0
+        has_fault |= (sc & SCHED_DO).any(axis=1)
+    do_scan = scan_step_mask(S, period, enabled=autonuma,
+                             start_step=start_step)
+    return scheds, do_free, do_scan, has_fault
 
 
 def lane_mesh(n_lanes: int, devices=None) -> Mesh:
@@ -177,9 +201,9 @@ def sweep_lanes(mc: MachineConfig,
                 lane_sharding=None,
                 engine: str = "blocked",
                 block: int = DEFAULT_BLOCK,
-                group: Optional[int] = None,
                 debug: bool = False,
                 telemetry=None,
+                states: Optional[Sequence[SimState]] = None,
                 ) -> List[RunResult]:
     """Run L independent (cost, policy, trace) lanes as one batched scan.
 
@@ -191,8 +215,6 @@ def sweep_lanes(mc: MachineConfig,
     ``budget`` (optional) raises the compiled AutoNUMA ``top_k`` bound
     above the per-lane maximum so repeated calls with different policy
     mixes reuse one executable; per-lane budgets still gate exactly.
-    ``group`` raises the allocator conflict-group bound the same way (the
-    computed bound is already power-of-two-quantized).
 
     ``engine`` / ``block`` select the stepper (see ``core.sim``):
     time-blocked windows by default, with event windows — the union over
@@ -208,11 +230,16 @@ def sweep_lanes(mc: MachineConfig,
     callers get the blocked/batched fast path.  Pass ``debug=True`` to
     run a reference path deliberately.
 
+    ``states`` (optional, one :class:`SimState` per lane) resumes each
+    lane from its own state instead of a fresh ``init_state``; the lanes
+    must share one step clock (``SimState.step``), since the scan
+    schedule is lane-shared.
+
     ``telemetry`` (optional :class:`repro.obs.Telemetry`) times the
     call's layer boundaries as spans: ``sweep.prepare`` (entry to
-    dispatch) holding ``sweep.schedule`` (fault schedules, conflict-group
-    bound, event masks), ``sweep.plan`` (lane stacking, window plan and
-    tiles) and ``sweep.stage`` (host-to-device copies, initial state,
+    dispatch) holding ``sweep.schedule`` (:func:`event_masks`: fault
+    schedules and event masks), ``sweep.plan`` (lane stacking, window
+    plan and tiles) and ``sweep.stage`` (host-to-device copies, initial state,
     shardings, runner lookup); ``sweep.device`` (dispatch to
     ``block_until_ready``, compile included on a cold call) and
     ``sweep.readback``.  It counts lanes, windows by kind (the lean ones
@@ -230,12 +257,7 @@ def sweep_lanes(mc: MachineConfig,
     bitwise-identical with telemetry on or off.
     """
     tel = or_null(telemetry)
-    if engine not in ("blocked", "per_step"):
-        raise ValueError(f"unknown engine {engine!r}")
-    if (engine != "blocked" or phase_b != "batched") and not debug:
-        raise ValueError(
-            f"engine={engine!r} phase_b={phase_b!r} are reference (oracle) "
-            "paths; pass debug=True to run them")
+    check_engine(engine, phase_b, debug)
     policies = list(policies)
     ccs = list(ccs)
     tr_list = list(traces)
@@ -246,6 +268,17 @@ def sweep_lanes(mc: MachineConfig,
         raise ValueError(
             f"lane lists disagree: {len(ccs)} costs, {L} policies, "
             f"{len(tr_list)} traces")
+    start = 0
+    if states is not None:
+        states = list(states)
+        if len(states) != L:
+            raise ValueError(f"{len(states)} states for {L} lanes")
+        clocks = sorted({int(np.asarray(st.step)) for st in states})
+        if len(clocks) > 1:
+            raise ValueError(
+                f"resumed lanes must share one step clock, got {clocks}; "
+                "the scan schedule is lane-shared")
+        start = clocks[0]
     shape = tr_list[0].va.shape
     S = shape[0]
 
@@ -288,26 +321,9 @@ def sweep_lanes(mc: MachineConfig,
             lane_of[i] = j
 
         with tel.span("sweep.schedule"):
-            scheds = [fault_schedule(tr, mc) for tr in uniq_traces]
-            eff_group: Optional[int] = None
-            if phase_b == "batched":
-                lane_group = min(
-                    pow2ceil(max(fault_group_bound(sc) for sc in scheds)),
-                    mc.n_threads)
-                if group is not None and group < lane_group:
-                    raise ValueError(
-                        f"group override {group} below the lane maximum "
-                        f"{lane_group}; a smaller conflict-group bound drops "
-                        "allocator requests")
-                eff_group = min(group if group is not None else lane_group,
-                                mc.n_threads)
-            do_free = np.zeros((S,), bool)
-            has_fault = np.zeros((S,), bool)
-            for sc, tr in zip(scheds, uniq_traces):
-                do_free |= np.asarray(tr.free_seg) >= 0
-                has_fault |= (sc & SCHED_DO).any(axis=1)
-            do_scan = scan_step_mask(
-                S, period, enabled=any(bool(p.autonuma) for p in policies))
+            scheds, do_free, do_scan, has_fault = event_masks(
+                mc, uniq_traces, period,
+                any(bool(p.autonuma) for p in policies), start)
 
         def lanes(per_trace, dtype):
             a = np.stack([np.asarray(x, dtype) for x in per_trace], axis=1)
@@ -326,10 +342,8 @@ def sweep_lanes(mc: MachineConfig,
                            has_fault, np.ones((S,), bool))
                 lane_axis_of_x = (1, 1, 1, 1, 1, None, None, None, None)
             else:
-                # window classification from the lane-union schedule; same
-                # 9-array order and pad fills as sim.blocked_xs
-                # (WINDOW_PAD_FILLS) — pad-row semantics must match the
-                # solo path
+                # window classification from the lane-union schedule; pad
+                # rows take sim.WINDOW_PAD_FILLS
                 plan = plan_windows(do_free, do_scan, has_fault, S,
                                     eff_block)
                 host_xs = tuple(window_tiles(
@@ -351,9 +365,12 @@ def sweep_lanes(mc: MachineConfig,
             seg_leafs = np.stack([np.asarray(seg_of_leaf_table(tr, mc))
                                   for tr in uniq_traces])
             seg_of_leaf = jnp.asarray(seg_leafs[lane_of])        # [L, n_leaf]
-            st0 = jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (L,) + a.shape),
-                init_state(mc))
+            if states is None:
+                st0 = jax.tree.map(
+                    lambda a: jnp.broadcast_to(a[None], (L,) + a.shape),
+                    init_state(mc))
+            else:
+                st0 = _stack_leaves(states)
 
             mesh = _resolve_lane_sharding(lane_sharding, L)
             if mesh is not None:
@@ -372,7 +389,7 @@ def sweep_lanes(mc: MachineConfig,
 
             geom = plan.geom if plan is not None else None
             run_sweep = _sweep_runner(mc, eff_budget, phase_b, engine,
-                                      eff_block, eff_group, geom)
+                                      eff_block, geom)
 
         if tel.enabled:
             tel.counter("sweep.calls", engine=engine).inc()
@@ -402,7 +419,10 @@ def sweep_lanes(mc: MachineConfig,
         final = jax.device_get(final)
         outs = [np.asarray(o) for o in jax.device_get(outs)]
         if tel.enabled:
-            count_alloc_rows(tel, "sweep", alloc_rows)
+            # replayed faulting rows by the allocator arm they took: the
+            # one-pass vector arm or the serialized slot scan
+            for path, n in zip(("batched", "scan"), np.asarray(alloc_rows)):
+                tel.counter("sweep.alloc_rows", path=path).inc(int(n))
     if engine == "blocked":
         # [n_windows, R_out, L] -> [steps, L]: pad and capacity-slack
         # rows dropped in step order via the plan's emission mask
@@ -462,3 +482,58 @@ def sweep(mc: MachineConfig,
         engine=engine, block=block, debug=debug, telemetry=telemetry)
     results = [flat[j * P_:(j + 1) * P_] for j in range(M)]
     return results[0] if single else results
+
+
+class TieredMemSimulator:
+    """Public facade: configure once, run traces under a policy bundle.
+
+    A run is a one-lane :func:`sweep_lanes` call, so it shares the sweep's
+    compile cache and its results are those of the matching sweep lane,
+    bit for bit.
+
+    ``phase_b`` selects the fault engine: ``"batched"`` (default, the
+    conflict-aware vectorized path) or ``"sequential"`` (the per-thread
+    ``fori_loop`` reference the batched engine is tested against).
+
+    ``engine`` selects the stepper: ``"blocked"`` (default — the
+    time-blocked fast path over ``block``-step windows, bit-identical to
+    per-step execution) or ``"per_step"`` (the retained one-step-per-scan
+    reference).  The reference paths are differential-testing oracles
+    and run only with ``debug=True`` (:func:`check_engine`).
+
+    ``telemetry`` (optional :class:`repro.obs.Telemetry`) records a
+    ``sim.run`` span holding the sweep's own spans and counters
+    (``sweep.*``, see :func:`sweep_lanes`), and ``sim.runs{engine=}``.
+    All hooks are host-side: the compiled program and its outputs are
+    bitwise-identical with telemetry on or off.
+    """
+
+    def __init__(self, mc: MachineConfig = MachineConfig(),
+                 cc: CostConfig = CostConfig(),
+                 pc: PolicyConfig = PolicyConfig(),
+                 phase_b: str = "batched",
+                 engine: str = "blocked",
+                 block: int = DEFAULT_BLOCK,
+                 debug: bool = False,
+                 telemetry=None):
+        check_engine(engine, phase_b, debug)
+        self.mc, self.cc, self.pc = mc, cc, pc
+        self.phase_b = phase_b
+        self.engine = engine
+        self.block = int(block)
+        self.debug = bool(debug)
+        self.telemetry = or_null(telemetry)
+
+    def run(self, trace: Trace, state: Optional[SimState] = None) -> RunResult:
+        """Simulate ``trace`` from ``state`` (a fresh machine by default;
+        a previous run's ``final_state`` continues it)."""
+        tel = self.telemetry
+        with tel.span("sim.run", steps=trace.n_steps, engine=self.engine,
+                      trace=trace.name):
+            res, = sweep_lanes(
+                self.mc, [self.cc], [self.pc], [trace],
+                phase_b=self.phase_b, engine=self.engine, block=self.block,
+                debug=self.debug, telemetry=tel,
+                states=None if state is None else [state])
+        tel.counter("sim.runs", engine=self.engine).inc()
+        return res

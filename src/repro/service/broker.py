@@ -730,14 +730,6 @@ class SimBroker:
         qbudget = _pow2ceil(min(
             max(int(p.query.policy.autonuma_budget) for p in pendings),
             mc.n_map))
-        # The allocator conflict-group bound is trace-content-derived, so
-        # letting sweep_lanes compute it per batch would mint up to
-        # log2(T)+1 executables per bucket as fault profiles vary across
-        # bursts.  Like the budget bound above, brokers trade the scan-
-        # depth cut for compile-key stability: pin the bound at its
-        # maximum (full thread depth — the pre-blocked-engine status quo
-        # for fault steps; per-lane results are unaffected).
-        qgroup = mc.n_threads if phase_b == "batched" else None
         ccs = [p.query.cost for p in pendings]
         pcs = [p.query.policy for p in pendings]
         trs = [p.trace for p in pendings]
@@ -757,7 +749,6 @@ class SimBroker:
         results = sweep_lanes(
             mc, ccs, pcs, trs, phase_b=phase_b, budget=qbudget,
             lane_sharding=self.lane_sharding, engine=engine,
-            group=qgroup,
             # queries on a reference path already carried debug=True
             # (SimQuery validates); degraded mode always isolates with it
             debug=(degraded or engine != "blocked" or phase_b != "batched"),

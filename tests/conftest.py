@@ -10,7 +10,7 @@ every test; tests that want the real committed history (the green-path
 gate test) read it by explicit path.
 
 The program keeps every compiled runner for the life of the process
-(``sim._RUN_CACHE``, ``sweep._SWEEP_CACHE`` and JAX's own caches), and
+(``sweep._SWEEP_CACHE`` and JAX's own caches), and
 on the CPU each compiled kernel holds its own memory mappings.  A test
 worker that runs several files would pile up the mappings of every
 file's programs and reach the kernel's per-process mapping limit, which
@@ -27,7 +27,6 @@ import pytest
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_programs():
     yield
-    importlib.import_module("repro.core.sim")._RUN_CACHE.clear()
     importlib.import_module("repro.core.sweep")._SWEEP_CACHE.clear()
     jax.clear_caches()
     gc.collect()

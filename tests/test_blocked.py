@@ -21,9 +21,9 @@ from repro.core import (CostConfig, MachineConfig, PolicyConfig,
 from repro.core import alloc as alloc_mod
 from repro.core.ref import OracleSim
 from repro.obs import Telemetry
-from repro.core.sim import (DEFAULT_BLOCK, SCHED_WINNER, blocked_xs,
-                            fault_group_bound, fault_schedule, plan_windows,
-                            pow2ceil)
+from repro.core.sim import (DEFAULT_BLOCK, plan_windows, pow2ceil,
+                            window_tiles)
+from repro.core.sweep import event_masks
 
 EXACT_KEYS = ("l1_hits", "stlb_hits", "walks", "walk_mem_reads", "faults",
               "slow_allocs", "data_migrations", "demotions",
@@ -54,6 +54,14 @@ def tiny_machine(**kw):
     return MachineConfig(l1_tlb_sets=4, l1_tlb_ways=2, stlb_sets=8,
                          stlb_ways=4, pde_pwc_entries=4,
                          pdpte_pwc_entries=2, **kw)
+
+
+def plan_of(mc, pc, trace, block):
+    """The window plan a one-lane run of ``trace`` under ``pc`` executes:
+    the sweep's own host schedule, classified by ``plan_windows``."""
+    _, *masks = event_masks(mc, [trace], int(pc.autonuma_period),
+                            bool(pc.autonuma))
+    return plan_windows(*masks, trace.n_steps, block)
 
 
 def make_trace(mc, va, free_at=None):
@@ -251,10 +259,11 @@ def test_window_tiling_shape_independence():
     rows back to exactly S steps."""
     mc = tiny_machine()
     pc = POLICIES[0]
-    a, plan_a = blocked_xs(steady_trace(mc, steps=100, seed=1), mc, pc,
-                           block=16)
-    b, plan_b = blocked_xs(fault_heavy_trace(mc, steps=100, seed=2), mc, pc,
-                           block=16)
+    tr_a = steady_trace(mc, steps=100, seed=1)
+    tr_b = fault_heavy_trace(mc, steps=100, seed=2)
+    plan_a, plan_b = (plan_of(mc, pc, tr, 16) for tr in (tr_a, tr_b))
+    a, b = (window_tiles([tr.va], 100, 16, rows_to=plan.rows_in)
+            for tr, plan in ((tr_a, plan_a), (tr_b, plan_b)))
     # window count from the shape alone, for any content
     assert a[0].shape[0] == b[0].shape[0] == 7      # ceil(100/16) windows
     assert plan_a.n_windows == plan_b.n_windows == 7
@@ -325,13 +334,7 @@ def test_alloc_many_conflict_groups_match_full_scan():
                                               err_msg=f"trial {trial}: {name}")
 
 
-def test_fault_group_bound_and_block_quantization():
-    mc = tiny_machine()
-    trace = fault_heavy_trace(mc, seed=9)
-    sched = fault_schedule(trace, mc)
-    bound = fault_group_bound(sched)
-    winners = ((sched & SCHED_WINNER) > 0).sum(axis=1)
-    assert bound == max(int(winners.max()), 1)
+def test_block_quantization():
     assert pow2ceil(5) == 8 and pow2ceil(8) == 8 and pow2ceil(0) == 1
     assert DEFAULT_BLOCK == 64
 
@@ -380,10 +383,10 @@ def test_lean_windows_bitwise(runner):
     mc = tiny_machine()
     cc = CostConfig()
     trace = populate_mix_trace(mc)
-    _, plan = blocked_xs(trace, mc, LEAN_POLICIES[0], block=16)
+    plan = plan_of(mc, LEAN_POLICIES[0], trace, 16)
     assert plan.n_lean == 3
     assert plan.counts == (1, 5, 1, 0)
-    _, plan = blocked_xs(trace, mc, LEAN_POLICIES[2], block=16)
+    plan = plan_of(mc, LEAN_POLICIES[2], trace, 16)
     assert plan.n_lean == 4
     tel = Telemetry()
     if runner == "solo":
@@ -394,7 +397,7 @@ def test_lean_windows_bitwise(runner):
                                   debug=True).run(trace)
                for pc in LEAN_POLICIES]
         # without AutoNUMA window 3 has no tick and is lean too
-        assert tel.metrics.value("sim.windows_lean") == 3 + 3 + 4
+        assert tel.metrics.value("sweep.windows_lean") == 3 + 3 + 4
     else:
         got = sweep(mc, cc, LEAN_POLICIES, trace, block=16, telemetry=tel)
         ref = sweep(mc, cc, LEAN_POLICIES, trace, engine="per_step",
@@ -460,9 +463,9 @@ def test_allocator_arms_bitwise_across_watermarks(runner):
             ref.append(TieredMemSimulator(
                 mc=mc, cc=cc, pc=pcs[i], engine="per_step", debug=True,
                 telemetry=tel_ref).run(traces[i]))
-            arms = [tel.metrics.value("sim.alloc_rows", path=p)
+            arms = [tel.metrics.value("sweep.alloc_rows", path=p)
                     for p in ("batched", "scan")]
-            assert arms == [tel_ref.metrics.value("sim.alloc_rows", path=p)
+            assert arms == [tel_ref.metrics.value("sweep.alloc_rows", path=p)
                             for p in ("batched", "scan")]
             assert min(arms) > 0, (i, arms)
             assert sum(arms) == len(traces[i].va) - delays[i]

@@ -26,6 +26,7 @@ Plus the reference-path gate: ``engine="per_step"`` / ``phase_b=
 "sequential"`` are debug-only everywhere (simulator, sweep, service).
 """
 import dataclasses
+import importlib
 
 import numpy as np
 import pytest
@@ -289,6 +290,9 @@ def test_broker_compiles_once_per_tier_topology():
     """Bucket keys include the machine: a burst mixing 2-tier and 3-tier
     queries of one trace shape compiles exactly once per topology, and a
     second burst with fresh trace content compiles zero more."""
+    # from an empty runner cache: an earlier test's run (a solo run is a
+    # one-lane sweep) may already have compiled one of these programs
+    importlib.import_module("repro.core.sweep")._SWEEP_CACHE.clear()
     mc2 = tiny_machine()
     mc3 = tiny_machine(tiers=TIER3)
     pols = [tpp(autonuma_period=16, autonuma_budget=32),

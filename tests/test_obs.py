@@ -337,10 +337,18 @@ def test_simulator_bitwise_identical_with_telemetry():
 
     m = tel.metrics
     assert m.value("sim.runs", engine="blocked") == 1
-    n_windows = m.value("sim.windows_fast") + m.value("sim.windows_event")
+    assert m.value("sweep.lanes", engine="blocked") == 1
+    n_windows = (m.value("sweep.windows_fast")
+                 + m.value("sweep.windows_event"))
     assert n_windows == math.ceil(160 / 64)
-    assert tel.tracer.span_names() == ["sim.run"]
     assert m.value("sim.run_seconds")["count"] == 1
+    # the one-lane sweep's spans sit inside the solo run's span
+    ev = [e for e in tel.tracer.events if e["ph"] == "X"]
+    run, = [e for e in ev if e["name"] == "sim.run"]
+    lo, hi = run["ts"], run["ts"] + run["dur"]
+    for name in ("sweep.prepare", "sweep.device", "sweep.readback"):
+        span, = [e for e in ev if e["name"] == name]
+        assert lo <= span["ts"] and span["ts"] + span["dur"] <= hi, name
 
 
 # ---------------------------------------------------------------------------
@@ -628,17 +636,17 @@ def hlo_reaches(comps, root, needle):
 
 @pytest.mark.parametrize("entry", ["sweep", "sim"])
 def test_scan_tick_counters_match_the_plan(entry):
-    """``sweep.scan_ticks`` (``sim.scan_ticks`` for a solo run) counts
-    the window plan's scan ticks by arm: in the ``mixed`` trace windows 1
-    and 3 hoist theirs and window 2 replays its tick inside a full
-    window.  Outputs stay bit-identical to telemetry off."""
-    from repro.core.sim import blocked_xs, scan_step_mask
-    from test_blocked import tiny_machine as blocked_machine
+    """``sweep.scan_ticks`` (a solo run publishes the same, as a one-lane
+    sweep) counts the window plan's scan ticks by arm: in the ``mixed``
+    trace windows 1 and 3 hoist theirs and window 2 replays its tick
+    inside a full window.  Outputs stay bit-identical to telemetry off."""
+    from repro.core.sim import scan_step_mask
+    from test_blocked import plan_of, tiny_machine as blocked_machine
     mc = blocked_machine()
     pc = PolicyConfig(data_policy=FIRST_TOUCH, pt_policy=PT_FOLLOW_DATA,
                       autonuma=True, autonuma_period=16, autonuma_budget=32)
     tr = scoped_trace(mc, "mixed")
-    _, plan = blocked_xs(tr, mc, pc, block=16)
+    plan = plan_of(mc, pc, tr, 16)
     assert plan.scan_ticks == (2, 1)
     assert sum(plan.scan_ticks) == int(scan_step_mask(64, 16).sum())
 
@@ -653,9 +661,9 @@ def test_scan_tick_counters_match_the_plan(entry):
                                     telemetry=tel).run(tr)
     assert_bitwise_equal(plain, traced, entry)
     m = tel.metrics
-    assert (m.value(f"{entry}.scan_ticks", arm="hoist"),
-            m.value(f"{entry}.scan_ticks", arm="replay")) == plan.scan_ticks
-    assert m.value(f"{entry}.windows_hoist") == plan.scan_ticks[0]
+    assert (m.value("sweep.scan_ticks", arm="hoist"),
+            m.value("sweep.scan_ticks", arm="replay")) == plan.scan_ticks
+    assert m.value("sweep.windows_hoist") == plan.scan_ticks[0]
 
 
 def read_host_spans(trace_dir):

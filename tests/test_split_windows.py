@@ -23,10 +23,10 @@ from repro.core import (CostConfig, PolicyConfig, FIRST_TOUCH, INTERLEAVE,
                         PT_BIND_HIGH, PT_FOLLOW_DATA, nomad, sweep,
                         sweep_compile_count, tpp)
 from repro.core.sim import (WIN_FAST, WIN_FULL, WIN_HOIST, WIN_SPLIT,
-                            blocked_xs, plan_windows)
+                            plan_windows)
 
 from test_blocked import (assert_blocked_matches_per_step, make_trace,
-                          steady_trace, tiny_machine)
+                          plan_of, steady_trace, tiny_machine)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -165,7 +165,7 @@ def test_hoist_engages_and_stays_bitwise():
         nomad(autonuma_period=16, autonuma_budget=32),
     ]
     for pc in families:
-        _, plan = blocked_xs(trace, mc, pc, block=16)
+        plan = plan_of(mc, pc, trace, 16)
         assert plan.counts[WIN_HOIST] > 0, pc.label()
         assert_blocked_matches_per_step(mc, pc, trace, cc, block=16)
 

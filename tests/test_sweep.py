@@ -6,13 +6,16 @@ independent ``TieredMemSimulator`` run and to the ``core.ref`` oracle,
 with cycle totals matching to float32 rounding.  The whole sweep must also
 compile exactly once per trace shape.
 """
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 
 from repro.core import (CostConfig, MachineConfig, PolicyConfig,
-                        TieredMemSimulator, Trace, pad_trace, sweep,
-                        sweep_compile_count, FIRST_TOUCH, INTERLEAVE,
-                        PT_BIND_ALL, PT_BIND_HIGH, PT_FOLLOW_DATA)
+                        TieredMemSimulator, Trace, init_state, pad_trace,
+                        sweep, sweep_compile_count, sweep_lanes, FIRST_TOUCH,
+                        INTERLEAVE, PT_BIND_ALL, PT_BIND_HIGH, PT_FOLLOW_DATA)
 from repro.core.ref import OracleSim
 
 EXACT_KEYS = ("l1_hits", "stlb_hits", "walks", "walk_mem_reads", "faults",
@@ -226,6 +229,74 @@ def test_sweep_rejects_mixed_periods_and_shapes():
         sweep(mc, cc, mixed, tr)
     with pytest.raises(ValueError, match="shape"):
         sweep(mc, cc, POLICIES, [tr, random_trace(mc, seed=42, steps=65)])
+
+
+def assert_runs_bitwise(a, b, what):
+    """Every timeline array and every final-state leaf, bit for bit."""
+    assert sorted(a.timeline) == sorted(b.timeline), what
+    for k in a.timeline:
+        np.testing.assert_array_equal(a.timeline[k], b.timeline[k],
+                                      err_msg=f"{what}: tl/{k}")
+    la, lb = jax.tree.leaves(a.final_state), jax.tree.leaves(b.final_state)
+    assert len(la) == len(lb), what
+    for i, (x, y) in enumerate(zip(la, lb)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f"{what}: leaf {i}")
+
+
+def test_solo_run_is_a_one_lane_sweep():
+    """``TieredMemSimulator.run`` is a one-lane ``sweep_lanes`` call: the
+    matching sweep reuses its compiled program (no new compile) and gives
+    the same timeline and final state bit for bit."""
+    mc = tiny_machine()
+    cc = CostConfig()
+    trace = random_trace(mc, steps=300, seed=61, free_at=150)
+    for pc in (POLICIES[1], POLICIES[2]):
+        solo = TieredMemSimulator(mc=mc, cc=cc, pc=pc).run(trace)
+        before = sweep_compile_count()
+        lane, = sweep_lanes(mc, [cc], [pc], [trace])
+        assert sweep_compile_count() == before, pc.label()
+        assert (solo.trace_name, solo.policy_label) \
+            == (lane.trace_name, lane.policy_label)
+        assert_runs_bitwise(solo, lane, pc.label())
+
+
+def split_trace(tr, cut):
+    def part(sl):
+        return dataclasses.replace(tr, va=tr.va[sl], is_write=tr.is_write[sl],
+                                   free_seg=tr.free_seg[sl], llc=tr.llc[sl])
+    return part(slice(None, cut)), part(slice(cut, None))
+
+
+@pytest.mark.parametrize("case", ["split", "clocks"])
+def test_sweep_lanes_resumes_from_states(case):
+    """``sweep_lanes(states=)`` resumes every lane from its own state: a
+    run split at step 75 (mid-window, off the scan period) equals the
+    whole run, state leaf for leaf and timeline bit for bit.  Lanes whose
+    step clocks differ are refused, since the scan schedule is
+    lane-shared."""
+    mc = tiny_machine()
+    ccs = [CostConfig(), CostConfig(nvmm_read=1500)]
+    pcs = [POLICIES[0], POLICIES[3]]
+    if case == "clocks":
+        st = init_state(mc)
+        states = [st, dataclasses.replace(st, step=st.step + 5)]
+        tr = random_trace(mc, steps=32, seed=71)
+        with pytest.raises(ValueError, match="step clock"):
+            sweep_lanes(mc, ccs, pcs, [tr, tr], states=states)
+        return
+    trs = [random_trace(mc, steps=160, seed=72, free_at=100),
+           random_trace(mc, steps=160, seed=73)]
+    whole = sweep_lanes(mc, ccs, pcs, trs, block=16)
+    halves = [split_trace(tr, 75) for tr in trs]
+    first = sweep_lanes(mc, ccs, pcs, [h[0] for h in halves], block=16)
+    second = sweep_lanes(mc, ccs, pcs, [h[1] for h in halves], block=16,
+                         states=[r.final_state for r in first])
+    for i, (w, a, b) in enumerate(zip(whole, first, second)):
+        joined = dataclasses.replace(b, timeline={
+            k: np.concatenate([a.timeline[k], b.timeline[k]])
+            for k in b.timeline})
+        assert_runs_bitwise(joined, w, f"lane {i}")
 
 
 def test_policy_config_rejects_bad_codes():

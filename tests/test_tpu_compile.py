@@ -98,8 +98,7 @@ def test_main_path_fits_one_chip(one_chip, monkeypatch):
                    run_steps=512).build(mc)
     pcs = [linux_default(), bhi(), bhi_mig(), bind_all()]
     with pytest.raises(_Captured):
-        sweep_lanes(mc, [CostConfig()] * 4, pcs, [tr] * 4,
-                    group=mc.n_threads)
+        sweep_lanes(mc, [CostConfig()] * 4, pcs, [tr] * 4)
     compiled = captured["runner"].lower(
         *on(one_chip, captured["args"])).compile()
     mem = compiled.memory_analysis()
